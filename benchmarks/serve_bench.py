@@ -117,6 +117,7 @@ from typing import Dict, List, Optional
 
 from benchmarks.common import add_target_arg, fmt_table, save_artifact, \
     target_scope
+from repro.launch.device import enable_compile_cache
 
 BENCH_ID = 10
 
@@ -1472,6 +1473,7 @@ def main(argv=None) -> int:
                          "BENCH_%d.json at the repo root" % BENCH_ID)
     add_target_arg(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     try:
         mesh_n = 1
         for part in args.mesh.split("x"):

@@ -52,7 +52,7 @@ def main() -> int:
 
     # ---- phase 1: "16 hosts" (here: 1x1 mesh stands in) -------------------
     mesh_a = make_host_mesh(1, 1)
-    with shd.use_mesh(mesh_a):
+    with jax.set_mesh(mesh_a):
         params = model.init(jax.random.PRNGKey(0))
         state = opt_mod.init_opt_state(params, tcfg.opt)
         step_fn = jax.jit(make_train_step(model, tcfg))
@@ -72,7 +72,7 @@ def main() -> int:
 
     # ---- phase 2: restart on the new mesh ---------------------------------
     mesh_b = make_host_mesh(1, 1)   # stands in for the re-sliced (15,16)
-    with shd.use_mesh(mesh_b):
+    with jax.set_mesh(mesh_b):
         tmpl = jax.eval_shape(
             lambda: {"params": model.init(jax.random.PRNGKey(0)),
                      "opt": opt_mod.init_opt_state(

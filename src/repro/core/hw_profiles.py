@@ -10,12 +10,16 @@ derived rows in the benchmarks — that round trip is the reproduction.
 TPU profiles carry the constants used for the roofline analysis
 (:mod:`benchmarks.roofline`): peak bf16 FLOP/s, HBM bandwidth, ICI link
 bandwidth, and the VMEM capacity that plays the role of MemPool's L1 SPM.
+VMEM is two facts: the physical capacity, and the scoped limit the TPU
+compiler grants one Pallas kernel by default. Kernel plans are budgeted
+against the scoped limit — a plan sized to the physical 128 MiB is refused
+at compile time (``RESOURCE_EXHAUSTED ... scoped vmem limit``).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 KiB = 1024
 MiB = 1024 * KiB
@@ -99,13 +103,21 @@ class TpuProfile:
     ici_link_bw: float         # bytes/s per link, per direction
     ici_links: int             # torus links per chip
     vmem_bytes: int            # the "shared-L1 SPM" of the TPU world
+    vmem_limit_bytes: Optional[int] = None   # scoped VMEM one kernel may use
     mxu_dim: int = 128         # systolic array edge -> matmul tiling alignment
     sublanes: int = 8          # VREG sublane count -> second-minor alignment
     dci_bw: float = 25.0e9     # inter-pod (data-center) bytes/s per chip, est.
+    device_kinds: Tuple[str, ...] = ()   # jax ``device_kind`` strings
 
     @property
     def ici_bw_total(self) -> float:
         return self.ici_link_bw * self.ici_links
+
+    @property
+    def kernel_vmem_bytes(self) -> int:
+        """VMEM one kernel may claim: the scoped limit where the profile
+        names one, else the whole physical capacity."""
+        return self.vmem_limit_bytes or self.vmem_bytes
 
 
 #: TPU v5e — the dry-run / roofline target (values from public spec sheets).
@@ -117,6 +129,8 @@ TPU_V5E = TpuProfile(
     ici_link_bw=50e9,
     ici_links=4,
     vmem_bytes=128 * MiB,
+    vmem_limit_bytes=16 * MiB,
+    device_kinds=("TPU v5 lite",),
 )
 
 #: TPU v5p, kept for profile-sweep experiments (beyond-paper exploration).
@@ -128,6 +142,8 @@ TPU_V5P = TpuProfile(
     ici_link_bw=100e9,
     ici_links=6,
     vmem_bytes=128 * MiB,
+    vmem_limit_bytes=16 * MiB,
+    device_kinds=("TPU v5",),
 )
 
 TPU_PROFILES = {p.name: p for p in (TPU_V5E, TPU_V5P)}

@@ -127,11 +127,19 @@ def clamp_matmul_plan(plan: tiling.MatmulPlan, m: int, k: int,
                              min(plan.bn, n), plan.n_buffers)
 
 
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
 def clamp_attention_plan(plan: tiling.AttentionPlan, seq_q: int,
                          seq_kv: int) -> tiling.AttentionPlan:
+    """Blocks never exceed the sequences rounded up to the 8-row sublane
+    tile; the caller pads both sequences to block multiples
+    (:func:`repro.kernels.ops.attention`), so a prompt of any length keeps
+    the capacity plan's blocks instead of degrading to tiny divisors."""
     return tiling.AttentionPlan(
-        shrink_to_divisor(plan.block_q, max(seq_q, 1)),
-        shrink_to_divisor(plan.block_kv, max(seq_kv, 1)),
+        min(plan.block_q, _round_up(max(seq_q, 1), 8)),
+        min(plan.block_kv, _round_up(max(seq_kv, 1), 8)),
         plan.n_buffers)
 
 

@@ -225,7 +225,9 @@ class HardwareTarget:
 
     ``scratchpad_level`` names the hierarchy level whose capacity the tile
     planner partitions: VMEM on TPU, the full shared-L1 cluster SPM on
-    MemPool (the paper's t-rule fills the whole pool).
+    MemPool (the paper's t-rule fills the whole pool). ``kernel_limit_bytes``
+    caps what one kernel may claim of that level — the TPU compiler's
+    scoped VMEM limit; ``None`` (MemPool) lets a tile fill the level.
     """
 
     name: str
@@ -236,6 +238,7 @@ class HardwareTarget:
     tile_align: int                    # block-edge alignment for plans
     word_bytes: int                    # native word the capacity rule counts
     db_margin: float = 0.0             # default double-buffer floor margin
+    kernel_limit_bytes: Optional[int] = None
 
     @property
     def scratchpad_bytes(self) -> int:
@@ -243,11 +246,17 @@ class HardwareTarget:
         assert cap is not None, self.scratchpad_level
         return cap
 
+    @property
+    def kernel_scratchpad_bytes(self) -> int:
+        """The share of the scratchpad one kernel's plan may fill."""
+        return self.kernel_limit_bytes or self.scratchpad_bytes
+
     def partition(self, *, fraction: float = 1.0, n_buffers: int = 2,
                   db_margin: Optional[float] = None) -> CapacityPartition:
-        """A :class:`CapacityPartition` of this target's scratchpad."""
+        """A :class:`CapacityPartition` of what one kernel may claim of
+        this target's scratchpad."""
         return CapacityPartition(
-            capacity_bytes=self.scratchpad_bytes, fraction=fraction,
+            capacity_bytes=self.kernel_scratchpad_bytes, fraction=fraction,
             n_buffers=n_buffers,
             db_margin=self.db_margin if db_margin is None else db_margin,
             align=self.tile_align, word_bytes=self.word_bytes)
@@ -268,7 +277,8 @@ def tpu_target(profile: TpuProfile) -> HardwareTarget:
     ))
     return HardwareTarget(
         name=profile.name, kind="tpu", hierarchy=hierarchy, profile=profile,
-        scratchpad_level="vmem", tile_align=profile.mxu_dim, word_bytes=2)
+        scratchpad_level="vmem", tile_align=profile.mxu_dim, word_bytes=2,
+        kernel_limit_bytes=profile.vmem_limit_bytes)
 
 
 #: MemPool bank-interleaving alignment: 4 banks/core x 8 interleave rows.
@@ -344,6 +354,19 @@ def _lookup(name: str) -> HardwareTarget:
     except KeyError:
         raise KeyError(f"unknown hardware target {name!r}; available: "
                        f"{', '.join(available_targets())}") from None
+
+
+def target_for_device_kind(device_kind: str) -> HardwareTarget:
+    """The registered TPU target whose profile names ``device_kind`` (as
+    jax reports it). An unknown kind is an error, never a default: plans and
+    pool budgets sized for the wrong chip fail at compile time or at run."""
+    with _LOCK:
+        for t in _REGISTRY.values():
+            if device_kind in getattr(t.profile, "device_kinds", ()):
+                return t
+    known = sorted(k for p in TPU_PROFILES.values() for k in p.device_kinds)
+    raise KeyError(f"no hardware target for device kind {device_kind!r}; "
+                   f"known: {', '.join(known)}")
 
 
 def set_target(target: Union[HardwareTarget, str, None]) -> Optional[HardwareTarget]:

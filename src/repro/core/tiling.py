@@ -107,7 +107,7 @@ def _resolve_partition(partition: Optional[CapacityPartition],
     if partition is not None:
         return partition
     if profile is not None:
-        return CapacityPartition(capacity_bytes=profile.vmem_bytes,
+        return CapacityPartition(capacity_bytes=profile.kernel_vmem_bytes,
                                  fraction=fraction, n_buffers=n_buffers,
                                  align=profile.mxu_dim)
     return get_target().partition(fraction=fraction, n_buffers=n_buffers)
@@ -118,8 +118,9 @@ class MatmulPlan:
     """Block sizes for a (M,K) @ (K,N) matmul kernel.
 
     vmem model (the TPU analogue of the paper's 3.25-tile working set):
-      n_buffers copies of the A and B blocks (double buffering of the HBM->VMEM
-      DMA pipeline) + one f32 accumulator block resident across the K loop.
+      n_buffers copies of the A and B blocks and of the C output block (the
+      HBM<->VMEM DMA pipeline buffers writeback like it buffers reads) + one
+      f32 accumulator block resident across the K loop.
     """
 
     bm: int
@@ -128,8 +129,9 @@ class MatmulPlan:
     n_buffers: int = 2
 
     def streamed_bytes(self, in_bytes: int = 2) -> int:
-        """One set of the streamed operand blocks (A + B)."""
-        return (self.bm * self.bk + self.bk * self.bn) * in_bytes
+        """One set of the pipelined blocks (A + B in, C out)."""
+        return (self.bm * self.bk + self.bk * self.bn
+                + self.bm * self.bn) * in_bytes
 
     def resident_bytes(self, acc_bytes: int = 4) -> int:
         return self.bm * self.bn * acc_bytes

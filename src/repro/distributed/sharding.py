@@ -13,10 +13,9 @@ back to replication (e.g. 4 KV heads on a 16-way model axis).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 DATA_AXIS = "data"
@@ -24,65 +23,15 @@ MODEL_AXIS = "model"
 POD_AXIS = "pod"
 
 
-# ---------------------------------------------------------------------------
-# jax version compatibility. jax.sharding.AxisType, jax.set_mesh and
-# jax.sharding.get_abstract_mesh only exist on newer jax; these shims keep a
-# single code path across versions (the seed's 42-failure AttributeError
-# storm on jax 0.4.x came from calling them unconditionally).
-# ---------------------------------------------------------------------------
-
-
-def axis_types_kwargs(n_axes: int, explicit: bool = False) -> Dict[str, Any]:
-    """``axis_types=`` kwargs for jax.make_mesh, or {} where unsupported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    kind = axis_type.Explicit if explicit else axis_type.Auto
-    return {"axis_types": (kind,) * n_axes}
-
-
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices: Optional[Sequence] = None,
               explicit: bool = False) -> jax.sharding.Mesh:
-    """jax.make_mesh with axis_types only where the running jax supports it."""
-    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
-                         **axis_types_kwargs(len(axes), explicit))
-
-
-def use_mesh(mesh: jax.sharding.Mesh):
-    """Context manager activating ``mesh`` as the ambient mesh."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh   # older jax: Mesh itself is the context manager
-
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        axis_names = kwargs.pop("axis_names", None)
-        if axis_names is not None:  # old spelling: auto = the complement
-            mesh_axes = frozenset(kwargs["mesh"].axis_names)
-            kwargs["auto"] = mesh_axes - frozenset(axis_names)
-        return _experimental_shard_map(f, **kwargs)
-
-
-def ambient_mesh():
-    """The ambient (context/thread-local) mesh, or None outside any."""
-    get_abs = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abs is not None:
-        return get_abs()
-    try:  # older jax: the pjit-era thread-local physical mesh
-        from jax._src import mesh as _mesh_lib
-        env_mesh = _mesh_lib.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    return None if env_mesh.empty else env_mesh
+    """jax.make_mesh with every axis Auto (GSPMD propagates shardings from
+    the ``shard`` constraints) or, with ``explicit``, every axis Explicit."""
+    kind = (jax.sharding.AxisType.Explicit if explicit
+            else jax.sharding.AxisType.Auto)
+    return jax.make_mesh(tuple(shape), tuple(axes), (kind,) * len(axes),
+                         devices=devices)
 
 #: batch-dimension sharding: span the pod axis too (multi-pod data
 #: parallelism). shard()/_fix_spec drop axes absent from the ambient mesh,
@@ -134,8 +83,8 @@ def shard(x: jax.Array, *spec) -> jax.Array:
     Axis names absent from the mesh are dropped from the spec; dims that do
     not divide the axis size are replicated.
     """
-    mesh = ambient_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     fixed = _fix_spec(_apply_layout(tuple(spec)), x.shape, mesh)
     return jax.lax.with_sharding_constraint(x, P(*fixed))
@@ -178,8 +127,8 @@ def fix_spec_for(mesh, spec: P, shape: Tuple[int, ...]) -> P:
 def model_axis_size(mesh=None) -> int:
     """Size of the `model` axis on ``mesh`` (ambient if None); 1 without one."""
     if mesh is None:
-        mesh = ambient_mesh()
-    if mesh is None or not getattr(mesh, "axis_names", ()):
+        mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return 1
     return axis_size(mesh, MODEL_AXIS)
 
@@ -195,6 +144,37 @@ def heads_divide(n_heads: int, mesh=None) -> bool:
     back to the seq-sharded (flash-decoding) layout."""
     m = model_axis_size(mesh)
     return m > 1 and n_heads % m == 0
+
+
+def kernel_heads_shard(n_kv_heads: int) -> bool:
+    """True iff a Pallas attention kernel over ``n_kv_heads`` KV heads can
+    run under the ambient mesh: no multi-device mesh, or a `model` axis
+    that divides the KV heads (see :func:`over_heads`). Otherwise callers
+    take their jnp route, which GSPMD partitions."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.size <= 1:
+        return True
+    return n_kv_heads % model_axis_size(mesh) == 0
+
+
+def over_heads(fn, head_args: Sequence[bool]):
+    """Run a Pallas kernel once per `model` shard, on that shard's heads.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"). Attention heads are independent, so a
+    shard_map that hands each shard its own slice of axis 1 — of every
+    argument flagged in ``head_args`` and of the output — computes exactly
+    the unsharded result; other arguments (block tables, lengths) are
+    replicated. With GQA the query and KV head slices line up whenever
+    :func:`kernel_heads_shard` holds. Identity without a multi-device
+    mesh."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.size <= 1:
+        return fn
+    head = P(None, MODEL_AXIS) if MODEL_AXIS in mesh.axis_names else P()
+    return jax.shard_map(fn, in_specs=tuple(head if h else P()
+                                            for h in head_args),
+                         out_specs=head, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

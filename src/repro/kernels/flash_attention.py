@@ -29,7 +29,8 @@ _STATS_LANES = 128  # stats scratch is (bq, 128) for TPU lane alignment
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                  scale: float, block_q: int, block_kv: int, n_kv: int,
-                 causal: bool, window: int | None, q_offset: int):
+                 causal: bool, window: int | None, q_offset: int,
+                 kv_len: int | None):
     iq, ik = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ik == 0)
@@ -48,6 +49,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         visible &= k_lo <= q_hi
     if window is not None:
         visible &= k_hi > q_lo - window
+    if kv_len is not None:
+        visible &= k_lo < kv_len
 
     @pl.when(visible)
     def _update():
@@ -63,6 +66,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             mask &= kpos <= qpos
         if window is not None:
             mask &= kpos > qpos - window
+        if kv_len is not None:
+            mask &= kpos < kv_len
         s = jnp.where(mask, s, _NEG_INF)
 
         m_prev = m_ref[:, :1]                          # (bq, 1)
@@ -85,16 +90,18 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "plan", "causal", "window", "scale", "q_offset", "interpret"))
+    "plan", "causal", "window", "scale", "q_offset", "kv_len", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     plan: AttentionPlan,
                     causal: bool = True,
                     window: int | None = None,
                     scale: float | None = None,
                     q_offset: int = 0,
+                    kv_len: int | None = None,
                     interpret: bool = False) -> jax.Array:
     """q: (B, Hq, Sq, D); k: (B, Hkv, Skv, D); v: (B, Hkv, Skv, Dv).
     Dv may differ from D (MLA decompressed heads). Sq % bq == Skv % bkv == 0.
+    ``kv_len`` masks key positions at or past it (right-padded K/V).
     """
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
@@ -109,7 +116,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, block_q=bq, block_kv=bkv, n_kv=n_kv,
-        causal=causal, window=window, q_offset=q_offset)
+        causal=causal, window=window, q_offset=q_offset, kv_len=kv_len)
 
     return pl.pallas_call(
         kernel,

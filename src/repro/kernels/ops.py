@@ -16,12 +16,14 @@ by the planner's shared pad/clamp helpers.
 
 from __future__ import annotations
 
+import functools
 from typing import Literal
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import planner, tiling
+from repro.distributed import sharding as shd
 from repro.kernels import ref
 from repro import runtime_flags
 from repro.kernels.flash_attention import flash_attention as _flash
@@ -31,13 +33,16 @@ from repro.kernels.mamba_scan import mamba_scan as _scan
 Impl = Literal["auto", "pallas", "ref"]
 
 
-def _on_tpu() -> bool:
+def on_tpu() -> bool:
+    """THE kernel-route switch: compiled Pallas kernels on a TPU backend,
+    the jnp oracles (or interpret mode) elsewhere. Every ``impl="auto"``
+    dispatch asks here, so a compile-only test can steer all of them."""
     return jax.default_backend() == "tpu"
 
 
 def _use_pallas(impl: Impl) -> bool:
     if impl == "auto":
-        return _on_tpu()
+        return on_tpu()
     return impl == "pallas"
 
 
@@ -67,7 +72,7 @@ def matmul(a: jax.Array, b: jax.Array, *,
     ap = _pad_to(_pad_to(a, 0, eff.bm), 1, eff.bk)
     bp = _pad_to(_pad_to(b, 0, eff.bk), 1, eff.bn)
     out = _matmul(ap, bp, plan=eff, out_dtype=out_dtype or a.dtype,
-                  interpret=not _on_tpu())
+                  interpret=not on_tpu())
     return out[:m, :n]
 
 
@@ -86,7 +91,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     a static one. The Pallas kernel needs a static grid offset, so traced
     offsets always take the reference path.
     """
-    if not _use_pallas(impl) or isinstance(q_offset, jax.Array):
+    if (not _use_pallas(impl) or isinstance(q_offset, jax.Array)
+            or not shd.kernel_heads_shard(k.shape[1])):
         # long sequences take the blockwise XLA path (bounded transients);
         # short ones take the direct softmax (cheaper compile, exact grads).
         # Cost-mode lowering (dry-run) unrolls the block scans with capped
@@ -112,8 +118,16 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                             in_bytes=q.dtype.itemsize)
     else:
         eff = planner.clamp_attention_plan(plan, sq, skv)
-    return _flash(q, k, v, plan=eff, causal=causal, window=window,
-                  scale=scale, q_offset=q_offset, interpret=not _on_tpu())
+    # pad both sequences to block multiples: padded query rows are cropped,
+    # padded key columns are masked by ``kv_len``
+    qp = _pad_to(q, 2, eff.block_q)
+    kp, vp = _pad_to(k, 2, eff.block_kv), _pad_to(v, 2, eff.block_kv)
+    flash = functools.partial(
+        _flash, plan=eff, causal=causal, window=window, scale=scale,
+        q_offset=q_offset, kv_len=skv if kp.shape[2] != skv else None,
+        interpret=not on_tpu())
+    out = shd.over_heads(flash, (True, True, True))(qp, kp, vp)
+    return out[:, :, :sq]
 
 
 def selective_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
@@ -137,4 +151,4 @@ def selective_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     while di % bd:
         bd //= 2
     return _scan(x, dt, a, b, c, d, plan=eff, block_d=max(bd, 1),
-                 interpret=not _on_tpu())
+                 interpret=not on_tpu())

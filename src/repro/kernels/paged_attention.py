@@ -43,7 +43,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.distributed.sharding import BATCH, MODEL_AXIS, heads_divide, shard
+from repro.distributed.sharding import (BATCH, MODEL_AXIS, heads_divide,
+                                       kernel_heads_shard, over_heads, shard)
+from repro.kernels import ops
 
 _NEG_INF = float("-inf")
 _STATS_LANES = 128   # stats scratch is (group, 128) for TPU lane alignment
@@ -286,7 +288,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     half the bytes, the math is unchanged. The Pallas kernel stays
     fp16-only for now, so quantized pools always take the gather path.
     """
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = ops.on_tpu()
     single = q.shape[2] == 1
     quantized = (k_scale is not None
                  or k_pages.dtype not in (jnp.bfloat16, jnp.float16,
@@ -295,12 +297,14 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         raise NotImplementedError(
             "the Pallas page walk reads fp16 pages; quantized pools "
             "dequantize on gather (impl='auto')")
-    use_pallas = (impl == "pallas") or (impl == "auto" and on_tpu and single
-                                        and not quantized)
+    use_pallas = (impl == "pallas") or (
+        impl == "auto" and on_tpu and single and not quantized
+        and kernel_heads_shard(k_pages.shape[1]))
     if use_pallas and causal:
-        return paged_flash_decode(q, k_pages, v_pages, block_tables,
-                                  cache_len, window=window,
-                                  interpret=not on_tpu)
+        walk = functools.partial(paged_flash_decode, window=window,
+                                 interpret=not on_tpu)
+        return over_heads(walk, (True, True, True, False, False))(
+            q, k_pages, v_pages, block_tables, cache_len)
     k = gather_kv_pages(k_pages, block_tables, seq_axis=1)
     v = gather_kv_pages(v_pages, block_tables, seq_axis=1)
     if k_scale is not None:

@@ -410,7 +410,7 @@ def _dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     # --- A: memory lowering (full depth) ------------------------------------
     t0 = time.time()
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled_mem = _lower_cell(model, shape, mesh, ov).compile()
     t_mem = time.time() - t0
     mem = compiled_mem.memory_analysis()
@@ -427,7 +427,7 @@ def _dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool,
         for k in (1, 2):
             cfg_k, full_reps = _scaled_cfg(cfg, k)
             model_k = build_model(cfg_k)
-            with shd.use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 compiled_k = _lower_cell(model_k, shape, mesh, ov,
                                          n_micro_override=1).compile()
             cost_k = compiled_k.cost_analysis()

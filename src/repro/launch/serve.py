@@ -43,7 +43,14 @@ Two modes through the same Engine (pooled KV cache):
 
 Hardware target selection: ``--target <name>`` (or ``REPRO_TARGET``) — the
 slot/page budgets are derived from that target's CapacityPartition
-(two-tier via its stacked TieredPartition in paged mode).
+(two-tier via its stacked TieredPartition in paged mode). On a TPU the
+default target is the one registered for the chip's ``device_kind``.
+
+Weights are random (seed 0) and made on the device already in the serving
+dtype (:func:`repro.serve.engine.init_serving_params`). Without
+``--layer0-bytes``/``--layer1-bytes`` the paged pool gets what the device
+has left beside them (:func:`repro.serve.pool.pool_bytes_beside`). The
+first line names the device the run is on.
 """
 
 from __future__ import annotations
@@ -59,9 +66,12 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.core.target import available_targets, use_target
 from repro.distributed import sharding as shd
+from repro.launch.device import (describe_devices, device_target,
+                                 enable_compile_cache)
 from repro.launch.mesh import make_cli_mesh
 from repro.models import build_model
-from repro.serve.engine import Engine, EngineConfig
+from repro.serve.engine import Engine, EngineConfig, init_serving_params
+from repro.serve.pool import pool_bytes_beside
 from repro.serve.scheduler import (DECODING, DRAINED, PREFILLING, Scheduler,
                                    derive_n_slots, derive_page_geometry,
                                    derive_prefill_chunk,
@@ -136,6 +146,7 @@ def run_stream(engine: Engine, scheduler: Scheduler, stream: list, *,
         "chunk_prefill_tokens": stats["chunk_prefill_tokens"],
         "prefill_chunks": stats["prefill_chunks"],
         "max_boundary_prefill_tokens": stats["max_boundary_prefill_tokens"],
+        "outputs": report.outputs,
     }
     if stats.get("speculate_tokens"):
         rec.update({k: stats[k] for k in (
@@ -250,14 +261,19 @@ def main(argv=None) -> int:
     if args.stream and (cfg.family == "encdec" or cfg.frontend_len):
         ap.error(f"--stream serves decoder-only token-prompt models; "
                  f"{cfg.name} ({cfg.family}) goes through one-shot mode")
+    enable_compile_cache()
+    dev = describe_devices()
+    print(f"device: platform={dev['platform']} kind={dev['kind']} "
+          f"count={dev['count']}", flush=True)
     mesh = make_cli_mesh(args.mesh, args.mesh_axes)
     data_shards = shd.axis_size(mesh, shd.DATA_AXIS)
     model_shards = shd.axis_size(mesh, shd.MODEL_AXIS)
 
-    tgt_ctx = use_target(args.target) if args.target else contextlib.nullcontext()
-    with tgt_ctx, shd.use_mesh(mesh):
+    target = args.target or device_target()
+    tgt_ctx = use_target(target) if target else contextlib.nullcontext()
+    with tgt_ctx, jax.set_mesh(mesh):
         model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(0))
+        params = init_serving_params(model, jax.random.PRNGKey(0), mesh)
         max_len = args.prompt_len + args.gen_len + cfg.frontend_len
         spec_k = args.speculate_tokens
         if spec_k == 0:
@@ -272,11 +288,13 @@ def main(argv=None) -> int:
         if args.stream:
             pages = None
             if args.paged:
+                layer0, layer1 = args.layer0_bytes, args.layer1_bytes
+                if layer0 is None and layer1 is None:
+                    layer0, layer1 = pool_bytes_beside(params)
                 pages = derive_page_geometry(
                     cfg, max_len, page_tokens=args.page_tokens,
                     max_slots=max(2, args.batch),
-                    layer0_bytes=args.layer0_bytes,
-                    layer1_bytes=args.layer1_bytes,
+                    layer0_bytes=layer0, layer1_bytes=layer1,
                     model_shards=model_shards,
                     kv_quant=args.kv_quant)
             n_slots = args.slots or derive_n_slots(

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.data.pipeline import DataConfig, SyntheticPipeline
 from repro.distributed import sharding as shd
+from repro.launch.device import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.train import optimizer as opt_mod
@@ -52,6 +53,7 @@ def parse_args(argv=None):
 
 def main(argv=None) -> int:
     args = parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch) if args.full else get_reduced(args.arch)
     model = build_model(cfg)
     d_mesh, m_mesh = (int(x) for x in args.mesh.split("x"))
@@ -73,7 +75,7 @@ def main(argv=None) -> int:
                                if args.fail_at is not None else ())
     detector = StragglerDetector()
 
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         params = jax.device_put(params, shd.named_shardings(params, mesh))
         state = opt_mod.init_opt_state(params, tcfg.opt)

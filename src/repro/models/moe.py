@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import ambient_mesh, shard, shard_map
+from repro.distributed.sharding import shard
 from repro.models.config import ModelConfig
 from repro.models.layers import cast, init_mlp, linear, mlp
 
@@ -273,7 +273,7 @@ def _moe_ep(p: Dict, x3: jax.Array, cfg: ModelConfig, mesh):
     # the token batch does not occupy the data axis (e.g. batch=1 decode).
     # Numerical equivalence with the dense path is asserted in
     # tests/dist_checks.py::check_moe_ep_matches_dense.
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   w_spec, w_spec, wd_spec),
@@ -289,10 +289,9 @@ def moe_block(p: Dict, x: jax.Array, *, cfg: ModelConfig,
               impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
     """x: (B, S, d) -> (y, aux_loss). Adds shared experts if configured."""
     b, s, d = x.shape
-    mesh = ambient_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     use_ep = (impl == "ep" or
-              (impl == "auto" and mesh is not None and
-               "model" in getattr(mesh, "axis_names", ()) and
+              (impl == "auto" and "model" in mesh.axis_names and
                cfg.n_experts % mesh.shape["model"] == 0 and
                mesh.shape["model"] > 1))
     if use_ep:

@@ -85,7 +85,45 @@ from repro.serve.pool import (DECODE_ROLE, PREFILL_ROLE, PoolManager,
                               PoolState)
 
 __all__ = ["Engine", "EngineConfig", "EngineCore", "PrefillRole",
-           "DecodeRole", "PoolState", "ServeReport"]
+           "DecodeRole", "PoolState", "ServeReport", "serving_params",
+           "init_serving_params"]
+
+
+#: Parameter leaves (final path component) that every use casts to the bf16
+#: compute dtype first: ``layers.linear`` weights, the embedding tables
+#: (``layers.embed`` / ``unembed_logits``) and the MLA absorbed ``wkv_b``.
+#: Biases, norm scales, SSM decay/skip terms, routers and the dense-path
+#: expert weights are used in f32 and stay f32.
+SERVING_BF16_LEAVES = frozenset({
+    "embed", "unembed", "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+    "wq_a", "wq_b", "wkv_a", "wkv_b", "in_proj", "x_proj", "dt_proj",
+    "out_proj"})
+
+
+def serving_params(params: Any) -> Any:
+    """Cast :data:`SERVING_BF16_LEAVES` to bf16, once.
+
+    Served tokens stay bit-identical (each use would have made the same
+    cast), the weights take half the bytes, and XLA no longer hoists the
+    per-use converts of the stacked layer weights out of the decode scan —
+    which held a second, bf16 copy of every weight beside the f32 one."""
+    def cast(path, leaf):
+        if (getattr(path[-1], "key", None) in SERVING_BF16_LEAVES
+                and leaf.dtype == jnp.float32):
+            return leaf.astype(jnp.bfloat16)
+        return leaf
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
+def init_serving_params(model: Model, key, mesh=None) -> Any:
+    """Random weights from ``key``, made on the device already cast by
+    :func:`serving_params` and, with ``mesh``, already tensor-parallel
+    placed — the f32 copy of a full-width model never exists whole."""
+    init = lambda k: serving_params(model.init(k))
+    out = None
+    if mesh is not None:
+        out = shd.named_shardings(jax.eval_shape(init, key), mesh)
+    return jax.jit(init, out_shardings=out)(key)
 
 
 @dataclasses.dataclass
@@ -173,9 +211,10 @@ class EngineCore:
     def __init__(self, model: Model, params: Any, ecfg: EngineConfig):
         self.model = model
         self.mesh = ecfg.mesh
+        params = serving_params(params)
         if self.mesh is not None:
-            # tensor-parallel weight placement; cache pools are placed by
-            # _place at init and the jitted fns run under _mesh_scope
+            # tensor-parallel weight placement; cache pools are built placed
+            # by _build and the jitted fns run under _mesh_scope
             params = jax.device_put(
                 params, shd.named_shardings(params, self.mesh))
         self.params = params
@@ -211,15 +250,20 @@ class EngineCore:
         is untouched."""
         if self.mesh is None:
             return contextlib.nullcontext()
-        return shd.use_mesh(self.mesh)
+        return jax.set_mesh(self.mesh)
 
-    def _place(self, tree):
-        """Commit a cache/pool tree to its mesh shardings (identity without
-        a mesh): head-axis placement for GQA caches/pages, replication for
-        latent/SSM state and scalars (``spec_for_cache``)."""
+    def _build(self, make):
+        """Run ``make`` (no arguments, returns a cache/pool tree) so its
+        outputs are born on their mesh shardings: head-axis placement for
+        GQA caches/pages, replication for latent/SSM state and scalars
+        (``spec_for_cache``). Under a mesh it is jitted with those
+        ``out_shardings``, so no device ever holds the whole pool — built
+        whole and then placed, a four-chip pool does not fit one chip.
+        Without a mesh it is called directly."""
         if self.mesh is None:
-            return tree
-        return jax.device_put(tree, shd.named_shardings(tree, self.mesh))
+            return make()
+        out = shd.named_shardings(jax.eval_shape(make), self.mesh)
+        return jax.jit(make, out_shardings=out)()
 
     # ------------------------------------------------------------ host IO
     def _fetch(self, tree, role: Optional[str] = None):
@@ -790,7 +834,7 @@ class Engine:
 
     def __init__(self, model: Model, params: Any, ecfg: EngineConfig):
         self.core = EngineCore(model, params, ecfg)
-        self.pools = PoolManager(model, ecfg, self.core._place)
+        self.pools = PoolManager(model, ecfg, self.core._build)
         self.prefill_role = PrefillRole(self.core, self.pools)
         self.decode_role = DecodeRole(self.core, self.pools)
         # ---- layer-2 host tier (DESIGN.md §Tiered KV compression & host

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.target import get_target
 from repro.serve import scheduler as sched_mod
 
 #: Engine role names (DESIGN.md §Disaggregated serving). The prefill role
@@ -40,6 +41,45 @@ from repro.serve import scheduler as sched_mod
 #: definitions live in the scheduler (routing is a scheduling decision).
 PREFILL_ROLE = sched_mod.PREFILL_ROLE
 DECODE_ROLE = sched_mod.DECODE_ROLE
+
+
+# ----------------------------------------------------- device pool budget
+
+#: How many copies of the layer-0 pool the jitted steps hold at once: the
+#: argument, the output (the steps do not donate the pool), and the decode
+#: scan's carry. Read off ``memory_analysis()`` of the pooled decode chunk
+#: at qwen2.5-3b widths on a v5e: output = pool, temp ~= pool + 1 GB.
+POOL_STEP_COPIES = 3
+
+
+def pool_bytes_beside(params: Any, *, target=None, fraction: float = 0.8,
+                      layer1_fraction: Optional[float] = None
+                      ) -> Tuple[int, int]:
+    """Per-device ``(layer0_bytes, layer1_bytes)`` that fit beside
+    ``params`` on the first device.
+
+    The device's ``bytes_limit`` (the target's pool-level capacity where the
+    backend reports none) less the bytes of ``params`` that live on it
+    leaves ``free``. ``fraction`` of it goes to the pool — the rest is for
+    the steps' activations — split between the tiers in the ratio
+    ``1 : layer1_fraction`` (the scheduler's die-split default) after
+    charging layer 0 :data:`POOL_STEP_COPIES` times. Pass the result as
+    ``derive_page_geometry(layer0_bytes=..., layer1_bytes=...)``: both are
+    per-shard budgets, as that function expects."""
+    target = target or get_target()
+    dev = jax.devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit") or \
+        sched_mod.pool_partition(target, fraction=1.0).capacity_bytes
+    weights = sum(s.data.nbytes for leaf in jax.tree.leaves(params)
+                  for s in leaf.addressable_shards if s.device == dev)
+    free = int(limit) - weights
+    if free <= 0:
+        raise ValueError(f"the weights take {weights} B of the device's "
+                         f"{limit} B; nothing is left for the KV pool")
+    if layer1_fraction is None:
+        layer1_fraction = sched_mod.default_layer1_fraction(target)
+    layer0 = int(fraction * free / (POOL_STEP_COPIES + layer1_fraction))
+    return layer0, int(layer0 * layer1_fraction)
 
 
 # ------------------------------------------------------------- tier codecs
@@ -116,16 +156,17 @@ class PoolManager:
 
     Exactly ONE PoolManager backs an engine, shared by its prefill and
     decode roles — the pool is a single address space (the paper's shared
-    L1), the roles are just who computes against it. ``place`` is the
-    engine core's mesh-placement function so pools land on the same
-    shardings as every jitted fn's output.
+    L1), the roles are just who computes against it. ``build`` is the
+    engine core's builder (:meth:`EngineCore._build`): it runs a pool
+    constructor so the arrays are born on the same mesh shardings as every
+    jitted fn's output.
     """
 
     def __init__(self, model: Any, ecfg: Any,
-                 place: Callable[[Any], Any]):
+                 build: Callable[[Callable[[], Any]], Any]):
         self.model = model
         self.ecfg = ecfg
-        self._place = place
+        self._build = build
         self._tier_copy: Dict[Tuple[str, str], Any] = {}   # jitted tier copies
         self._geom = None           # PageGeometry after init_paged_pool
         # ---- disaggregated slot ownership (role name per occupied slot).
@@ -148,15 +189,19 @@ class PoolManager:
                 "pooled serving takes token prompts; frontend-embed "
                 "requests go through one-shot generate()")
         from repro.models import transformer
-        state = {"caches": transformer.init_caches(cfg, n_slots,
-                                                   self.ecfg.max_len)}
-        zeros = jnp.zeros((n_slots,), jnp.int32)
-        return self._place(PoolState(
-            state=state,
-            tok=jnp.full((n_slots,), self.ecfg.pad_token, jnp.int32),
-            cache_len=zeros,
-            done=jnp.ones((n_slots,), bool),
-            n_gen=zeros, budget=zeros))
+
+        def make():
+            state = {"caches": transformer.init_caches(cfg, n_slots,
+                                                       self.ecfg.max_len)}
+            zeros = jnp.zeros((n_slots,), jnp.int32)
+            return PoolState(
+                state=state,
+                tok=jnp.full((n_slots,), self.ecfg.pad_token, jnp.int32),
+                cache_len=zeros,
+                done=jnp.ones((n_slots,), bool),
+                n_gen=zeros, budget=zeros)
+
+        return self._build(make)
 
     def init_paged_pool(self, sch: sched_mod.Scheduler
                         ) -> Tuple[PoolState, Dict[str, Any]]:
@@ -185,21 +230,25 @@ class PoolManager:
                 "bounded per-page error story (docs/SERVING.md)")
         self._geom = geom
         n_slots = sch.n_slots
-        state = {"caches": transformer.init_paged_caches(
-            cfg, n_slots, geom.n_pages, geom.page_tokens,
-            dtype=l0.dtype, quant_scales=l0.scaled)}
-        spill = transformer.init_paged_caches(
-            cfg, geom.n_spill_pages, geom.n_spill_pages, geom.page_tokens,
-            dtype=l1.dtype, quant_scales=l1.scaled)
-        zeros = jnp.zeros((n_slots,), jnp.int32)
-        pool = PoolState(
-            state=state,
-            tok=jnp.full((n_slots,), self.ecfg.pad_token, jnp.int32),
-            cache_len=zeros, done=jnp.ones((n_slots,), bool),
-            n_gen=zeros, budget=zeros,
-            block_tables=jnp.zeros((n_slots, geom.max_pages_per_slot),
-                                   jnp.int32))
-        return self._place(pool), self._place(spill)
+
+        def make():
+            state = {"caches": transformer.init_paged_caches(
+                cfg, n_slots, geom.n_pages, geom.page_tokens,
+                dtype=l0.dtype, quant_scales=l0.scaled)}
+            spill = transformer.init_paged_caches(
+                cfg, geom.n_spill_pages, geom.n_spill_pages,
+                geom.page_tokens, dtype=l1.dtype, quant_scales=l1.scaled)
+            zeros = jnp.zeros((n_slots,), jnp.int32)
+            pool = PoolState(
+                state=state,
+                tok=jnp.full((n_slots,), self.ecfg.pad_token, jnp.int32),
+                cache_len=zeros, done=jnp.ones((n_slots,), bool),
+                n_gen=zeros, budget=zeros,
+                block_tables=jnp.zeros((n_slots, geom.max_pages_per_slot),
+                                       jnp.int32))
+            return pool, spill
+
+        return self._build(make)
 
     # -------------------------------------------------------- tier copies
     def tier_copy_fn(self, src_codec: str = "fp16", dst_codec: str = "fp16"):

@@ -341,9 +341,14 @@ def pool_tiers(target: Optional[HardwareTarget] = None, *,
     """
     target = target or get_target()
     if layer1_fraction is None:
-        flow = getattr(target.profile, "flow", None)
-        layer1_fraction = 1.0 if flow == "3D" else 0.5
+        layer1_fraction = default_layer1_fraction(target)
     return pool_partition(target, fraction=fraction).stacked(layer1_fraction)
+
+
+def default_layer1_fraction(target: HardwareTarget) -> float:
+    """Spill-tier size relative to layer 0: a full second layer on a
+    MemPool-3D target, half a layer on 2D and TPU targets."""
+    return 1.0 if getattr(target.profile, "flow", None) == "3D" else 0.5
 
 
 @dataclasses.dataclass(frozen=True)

@@ -36,8 +36,8 @@ def _cast_params_for_compute(params):
     bf16, halving gather bytes vs gathering f32 then casting (§Perf,
     qwen2.5/h3). Numerics are unchanged: layers already cast weights to
     bf16 at use; this moves the cast before the gather."""
-    mesh = shd.ambient_mesh()
-    have_mesh = mesh is not None and bool(mesh.axis_names)
+    mesh = jax.sharding.get_abstract_mesh()
+    have_mesh = bool(mesh.axis_names)
     flat, tdef = jax.tree_util.tree_flatten_with_path(params)
     out = []
     for path, p in flat:
@@ -61,8 +61,8 @@ def _constrain_grads_like_params(grads, params):
     production, so GSPMD lowers the DP gradient reduction as a
     reduce-scatter onto the FSDP shards (half the wire bytes of the
     all-reduce it otherwise coalesces). §Perf hypothesis log, qwen2.5/h2."""
-    mesh = shd.ambient_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return grads
     specs = shd.param_specs(params, mesh)
     return jax.tree.map(
@@ -159,7 +159,7 @@ def make_compressed_train_step(model: Model, tcfg: TrainConfig, mesh):
             return loss, metrics, grads, new_err
 
         pspec = jax.tree.map(lambda _: P(), params)
-        loss, metrics, grads, new_err = shd.shard_map(
+        loss, metrics, grads, new_err = jax.shard_map(
             per_pod, mesh=mesh,
             in_specs=(pspec, pspec, P("pod")),
             out_specs=(P(), P(), pspec, pspec),

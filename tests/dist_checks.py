@@ -38,7 +38,7 @@ def check_moe_ep_matches_dense():
     y_dense, aux_dense = moe_mod.moe_block(p, x, cfg=cfg, impl="dense")
 
     mesh = shd.make_mesh((2, 4), ("data", "model"))
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_ep, aux_ep = jax.jit(
             lambda p, x: moe_mod.moe_block(p, x, cfg=cfg, impl="ep"))(p, x)
     np.testing.assert_allclose(np.asarray(y_ep, np.float32),
@@ -61,7 +61,7 @@ def check_moe_ep_capacity_drops():
     p = moe_mod.init_moe(cfg, key)
     x = jax.random.normal(jax.random.fold_in(key, 1), (4, 16, cfg.d_model)) * 0.1
     mesh = shd.make_mesh((2, 4), ("data", "model"))
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y, _ = jax.jit(lambda p, x: moe_mod.moe_block(p, x, cfg=cfg,
                                                       impl="ep"))(p, x)
     assert bool(jnp.isfinite(y).all())
@@ -81,7 +81,7 @@ def check_moe_partial_k_matches_dense():
                           jnp.float32) * 0.1
     y_dense, aux_dense = moe_mod.moe_block(p, x, cfg=cfg, impl="dense")
     mesh = shd.make_mesh((2, 4), ("data", "model"))
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_ep, aux_ep = jax.jit(
             lambda p, x: moe_mod.moe_block(p, x, cfg=cfg, impl="ep"))(p, x)
     np.testing.assert_allclose(np.asarray(y_ep, np.float32),
@@ -103,7 +103,7 @@ def check_compressed_psum():
     def body(g, e):
         return collectives.compressed_psum_mean(g, e, "pod", 8)
 
-    out, new_err = jax.jit(shd.shard_map(
+    out, new_err = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("pod"), P("pod")),
         out_specs=(P("pod"), P("pod"))))(jnp.asarray(g_local), jnp.asarray(err0))
     true_mean = g_local.mean(axis=0)
@@ -119,7 +119,7 @@ def check_compressed_psum():
     acc = np.zeros_like(true_mean)
     rounds = 16
     for _ in range(rounds):
-        out, e = jax.jit(shd.shard_map(
+        out, e = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P("pod"), P("pod")),
             out_specs=(P("pod"), P("pod"))))(jnp.asarray(g_local), e)
         acc += np.asarray(out)[0]
@@ -140,7 +140,7 @@ def check_sharded_train_step():
     cfg = get_reduced("yi-6b")
     model = build_model(cfg)
     mesh = shd.make_mesh((2, 4), ("data", "model"))
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         params = jax.device_put(params, shd.named_shardings(params, mesh))
         tcfg = TrainConfig(n_microbatches=2)
@@ -175,7 +175,7 @@ def check_pooled_decode():
 
     mesh = shd.make_mesh((2, 4), ("data", "model"))
     from repro.launch.dryrun import decode_shard_specs
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         inputs = {"tokens": nxt, "state": state, "cache_len": cache_len}
         specs = decode_shard_specs(jax.eval_shape(lambda: inputs), mesh,
                                    batch=2)

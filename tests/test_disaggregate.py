@@ -38,7 +38,7 @@ def _geometry(cfg, n_layer0=40, n_layer1=64):
 def _bare_pools():
     """Ownership bookkeeping touches neither the model nor the pool
     arrays, so a PoolManager with no model is a valid unit-test subject."""
-    return PoolManager(None, None, lambda x: x)
+    return PoolManager(None, None, lambda make: make())
 
 
 def test_transfer_ownership_flips_one_entry():

@@ -86,6 +86,27 @@ def test_attention_sweep(hq, hkv, causal, window):
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("sq,skv,offset,causal", [
+    (37, 37, 0, True),        # shorter than one 8-row tile
+    (200, 330, 0, True),      # prefill into a deeper cache (engine shape)
+    (100, 300, 100, True),    # resumed at a static offset
+    (70, 90, 0, False),       # padded keys must be masked, not attended
+])
+def test_attention_entry_pads_unaligned_lengths(sq, skv, offset, causal):
+    """``ops.attention`` pads both sequences to block multiples and masks
+    the padded keys (``kv_len``), so the kernel keeps aligned blocks at any
+    prompt length and still matches the oracle."""
+    q = jax.random.normal(_k(20), (1, 4, sq, 64), jnp.float32)
+    k = jax.random.normal(_k(21), (1, 2, skv, 64), jnp.float32)
+    v = jax.random.normal(_k(22), (1, 2, skv, 64), jnp.float32)
+    plan = tiling.AttentionPlan(block_q=64, block_kv=128)
+    got = ops.attention(q, k, v, causal=causal, q_offset=offset, plan=plan,
+                        impl="pallas")
+    want = ref.attention_ref(q, k, v, causal=causal, q_offset=offset)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_attention_dtypes(dtype):
     b, h, s, d = 1, 2, 128, 64

@@ -229,3 +229,58 @@ def test_prompt_padding_rejected_for_ssm():
     with pytest.raises(ValueError, match="prompt_pad_multiple"):
         Engine(model, params, EngineConfig(max_len=32,
                                            prompt_pad_multiple=8))
+
+
+# ------------------------------------------------------- serving weights
+
+def test_serving_params_cast_only_leaves_every_use_casts():
+    from repro.serve.engine import serving_params
+    model = build_model(dataclasses.replace(TINY, qkv_bias=True))
+    params = model.init(jax.random.PRNGKey(0))
+    cast = serving_params(params)
+    flat = jax.tree_util.tree_flatten_with_path(cast)[0]
+    dtypes = {jax.tree_util.keystr(p): a.dtype for p, a in flat}
+    for name, dt in dtypes.items():
+        leaf = name.rsplit("'", 2)[-2]
+        want = (jnp.bfloat16 if leaf in ("embed", "unembed", "wq", "wk",
+                                         "wv", "wo", "w_gate", "w_up",
+                                         "w_down")
+                else jnp.float32)
+        assert dt == want, (name, dt)
+    # values are exactly the cast each use would have made
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(cast)):
+        np.testing.assert_array_equal(np.asarray(a.astype(b.dtype)),
+                                      np.asarray(b))
+
+
+def test_engine_tokens_unchanged_by_the_serving_cast():
+    """An engine handed bf16 serving weights decodes the same tokens as one
+    handed the f32 originals (the cast happens once, in both)."""
+    from repro.serve.engine import init_serving_params, serving_params
+    model = build_model(TINY)
+    params = model.init(jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(5), (2, 8), 2,
+                              TINY.vocab_size)
+    ecfg = EngineConfig(max_len=64, eos_token=1)
+    a, _ = Engine(model, params, ecfg).generate({"tokens": toks}, n_steps=6)
+    b, _ = Engine(model, serving_params(params), ecfg).generate(
+        {"tokens": toks}, n_steps=6)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    made = init_serving_params(model, jax.random.PRNGKey(0))
+    assert made["tok"]["embed"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(made["tok"]["embed"]),
+        np.asarray(params["tok"]["embed"].astype(jnp.bfloat16)))
+
+
+def test_pool_bytes_beside_leaves_room_for_the_weights():
+    from repro.core.target import get_target
+    from repro.serve.pool import POOL_STEP_COPIES, pool_bytes_beside
+    params = build_model(TINY).init(jax.random.PRNGKey(0))
+    target = get_target("tpu-v5e")
+    hbm = target.hierarchy.level("hbm").capacity_bytes
+    weights = sum(a.nbytes for a in jax.tree.leaves(params))
+    l0, l1 = pool_bytes_beside(params, target=target)
+    assert l1 == int(l0 * 0.5)                 # the TPU die-split ratio
+    assert POOL_STEP_COPIES * l0 + l1 <= 0.8 * (hbm - weights)
+    assert POOL_STEP_COPIES * l0 + l1 >= 0.8 * (hbm - weights) - 4

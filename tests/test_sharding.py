@@ -147,6 +147,6 @@ def test_shard_inside_jit_with_mesh(mesh):
     def f(x):
         return shd.shard(x, "data", "model") * 2
 
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y = f(jnp.ones((4, 4)))
     assert (y == 2).all()

@@ -24,6 +24,10 @@ def test_default_target_is_v5e():
     assert t.profile is TPU_V5E
     assert t.hierarchy.names == ("vmem", "hbm", "ici", "dci")
     assert t.scratchpad_bytes == TPU_V5E.vmem_bytes
+    # plans budget against the scoped limit one kernel may claim, not the
+    # physical 128 MiB (a 128 MiB plan is refused by the TPU compiler)
+    assert t.kernel_scratchpad_bytes == TPU_V5E.vmem_limit_bytes == 16 * MiB
+    assert t.partition().capacity_bytes == 16 * MiB
 
 
 def test_registry_has_all_profiles():
@@ -43,6 +47,17 @@ def test_get_by_name_and_normalization():
 def test_unknown_target_raises_with_choices():
     with pytest.raises(KeyError, match="tpu-v5e"):
         get_target("tpu-v9000")
+
+
+def test_device_kind_maps_to_a_registered_target():
+    from repro.core.target import target_for_device_kind
+    assert target_for_device_kind("TPU v5 lite").name == "tpu-v5e"
+    assert target_for_device_kind("TPU v5").name == "tpu-v5p"
+    # an unknown chip is an error, never the v5e default
+    with pytest.raises(KeyError, match="TPU v5 lite"):
+        target_for_device_kind("TPU v9")
+    with pytest.raises(KeyError):
+        target_for_device_kind("cpu")
 
 
 def test_set_target_and_restore():
@@ -132,8 +147,11 @@ def test_target_partition_respects_scratchpad():
     for name in ("tpu-v5e", "mempool-3d-8mib"):
         t = get_target(name)
         part = t.partition(fraction=0.5)
-        assert part.budget_bytes == int(t.scratchpad_bytes * 0.5)
+        assert part.budget_bytes == int(t.kernel_scratchpad_bytes * 0.5)
         assert part.align == t.tile_align
+    # MemPool tiles may fill the whole cluster SPM (no scoped limit)
+    mp = get_target("mempool-3d-8mib")
+    assert mp.kernel_scratchpad_bytes == mp.scratchpad_bytes
 
 
 # ----------------------------------------------------------------- plan cache

@@ -12,7 +12,31 @@ from repro.core.hw_profiles import MiB, TPU_V5E, TpuProfile
 def test_plan_matmul_respects_budget_and_alignment():
     plan = tiling.plan_matmul(4096, 4096, 4096)
     assert plan.bm % 128 == 0 and plan.bk % 128 == 0 and plan.bn % 128 == 0
-    assert plan.vmem_bytes() <= TPU_V5E.vmem_bytes * 0.75
+    assert plan.vmem_bytes() <= TPU_V5E.vmem_limit_bytes * 0.75
+
+
+def test_tpu_plans_fit_the_scoped_vmem_limit():
+    """Plans budget against the 16 MiB a kernel may claim, not the physical
+    128 MiB: 2048x2048 attention blocks and 4096x1024x4096 matmul blocks,
+    planned against 128 MiB, were refused by the TPU compiler."""
+    from repro.core import planner
+    attn = planner.attention_kernel_plan(4096, 4096, 128, in_bytes=2)
+    assert attn.vmem_bytes(128) <= TPU_V5E.vmem_limit_bytes * 0.5
+    assert (attn.block_q, attn.block_kv) == (2048, 512)
+    mm = planner.matmul_kernel_plan(4096, 4096, 4096, in_bytes=2)
+    assert mm.vmem_bytes() <= TPU_V5E.vmem_limit_bytes * 0.75
+    assert (mm.bm, mm.bk, mm.bn) == (1024, 512, 1024)
+
+
+def test_clamped_attention_blocks_stay_aligned():
+    """Clamping never shrinks a block to a divisor of an unaligned length:
+    blocks stay multiples of the 8-row tile (the entry pads instead)."""
+    from repro.core import planner
+    plan = planner.attention_kernel_plan(1999, 4128, 128, in_bytes=2)
+    assert plan.block_q % 8 == 0 and plan.block_kv % 8 == 0
+    assert plan.block_q >= 512 and plan.block_kv >= 512
+    small = planner.attention_kernel_plan(37, 48, 128, in_bytes=2)
+    assert (small.block_q, small.block_kv) == (40, 48)
 
 
 def test_plan_matmul_grows_with_capacity():
@@ -60,7 +84,7 @@ def test_plan_matmul_properties(m, k, n, vmem_mib):
 def test_plan_attention_properties(sq, skv, hd):
     plan = tiling.plan_attention(sq, skv, hd)
     assert plan.block_q >= 128 and plan.block_kv >= 128
-    assert plan.vmem_bytes(hd) <= TPU_V5E.vmem_bytes * 0.5
+    assert plan.vmem_bytes(hd) <= TPU_V5E.vmem_limit_bytes * 0.5
     assert plan.block_q <= 2048 and plan.block_kv <= 2048
 
 
@@ -71,7 +95,7 @@ def test_plan_attention_properties(sq, skv, hd):
 def test_plan_scan_chunk_properties(seq, di, ds):
     plan = tiling.plan_scan_chunk(seq, di, ds)
     assert plan.chunk >= 8
-    assert plan.vmem_bytes(di, ds) <= TPU_V5E.vmem_bytes * 0.5
+    assert plan.vmem_bytes(di, ds) <= TPU_V5E.vmem_limit_bytes * 0.5
 
 
 @hypothesis.given(spm_kib=st.integers(64, 64 * 1024))
