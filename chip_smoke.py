@@ -18,7 +18,8 @@ One chip (the default):
     full published widths of qwen2.5-3b. Prompts run up to 4096 tokens, so
     prefill reaches the flash kernel's 2048 and 4096 sequence buckets;
     answers are 32 tokens. The pool gets what the device has left beside
-    the weights (``pool_bytes_beside``).
+    the weights (``pool_bytes_beside``), all of it: no slot-count cap, so
+    the run proves that the budget fits.
   * **check** — every request drains with in-vocabulary tokens; each first
     token equals the engine's one-shot ``generate`` for the same prompt; the
     Pallas page walk matches the ``decode_attention_masked`` oracle at the
@@ -29,8 +30,10 @@ One chip (the default):
 Four chips (``--four-chips``, only this phase): mistral-nemo-12b at full
 width served on a 1x4 ``model`` mesh (its 8 KV heads shard the pages 4
 ways). First tokens are compared with the same engine's mesh-4 one-shot
-``generate``, and each device's ``bytes_in_use`` and weight bytes are
-printed: no device may hold more than ``MAX_DEVICE_SKEW`` times the mean.
+``generate``; the compiled mesh admission and decode programs must hold
+``tpu_custom_call`` (the kernels ran per shard, not the jnp route); and
+each device's ``bytes_in_use`` and weight bytes are printed: no device may
+hold more than ``MAX_DEVICE_SKEW`` times the mean.
 
 Earlier lines report compile seconds (``backend_compile_duration`` events,
 persistent-cache hits included), stream wall time and tokens, peak device
@@ -126,8 +129,7 @@ def serve_stream(cfg, stream: list, *, seed: int, n_slots: int,
     layer0, layer1 = pool_bytes_beside(params)
     model_shards = 1 if mesh is None else mesh.shape["model"]
     geom = derive_page_geometry(cfg, max_len, page_tokens=PAGE_TOKENS,
-                                max_slots=2 * n_slots, layer0_bytes=layer0,
-                                layer1_bytes=layer1,
+                                layer0_bytes=layer0, layer1_bytes=layer1,
                                 model_shards=model_shards)
     engine = Engine(model, params, EngineConfig(
         max_len=max_len, sync_interval=SYNC_INTERVAL, mesh=mesh))
@@ -301,6 +303,7 @@ def four_chips(dev: dict, clock: CompileClock, seed: int) -> list:
         print(stream_line(cfg, served, dev), flush=True)
         failed = run_checks([
             ("stream", lambda: check_stream(cfg, stream, served)),
+            ("kernels", lambda: check_custom_calls(served, stream)),
             ("spread", lambda: check_spread(served, list(mesh.devices.flat))),
         ])
     print(clock.line("total", dev), flush=True)

@@ -19,7 +19,7 @@ at compile time (``RESOURCE_EXHAUSTED ... scoped vmem limit``).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 KiB = 1024
 MiB = 1024 * KiB
@@ -103,7 +103,7 @@ class TpuProfile:
     ici_link_bw: float         # bytes/s per link, per direction
     ici_links: int             # torus links per chip
     vmem_bytes: int            # the "shared-L1 SPM" of the TPU world
-    vmem_limit_bytes: Optional[int] = None   # scoped VMEM one kernel may use
+    vmem_limit_bytes: int      # scoped VMEM one kernel may use
     mxu_dim: int = 128         # systolic array edge -> matmul tiling alignment
     sublanes: int = 8          # VREG sublane count -> second-minor alignment
     dci_bw: float = 25.0e9     # inter-pod (data-center) bytes/s per chip, est.
@@ -112,12 +112,6 @@ class TpuProfile:
     @property
     def ici_bw_total(self) -> float:
         return self.ici_link_bw * self.ici_links
-
-    @property
-    def kernel_vmem_bytes(self) -> int:
-        """VMEM one kernel may claim: the scoped limit where the profile
-        names one, else the whole physical capacity."""
-        return self.vmem_limit_bytes or self.vmem_bytes
 
 
 #: TPU v5e — the dry-run / roofline target (values from public spec sheets).

@@ -107,7 +107,7 @@ def _resolve_partition(partition: Optional[CapacityPartition],
     if partition is not None:
         return partition
     if profile is not None:
-        return CapacityPartition(capacity_bytes=profile.kernel_vmem_bytes,
+        return CapacityPartition(capacity_bytes=profile.vmem_limit_bytes,
                                  fraction=fraction, n_buffers=n_buffers,
                                  align=profile.mxu_dim)
     return get_target().partition(fraction=fraction, n_buffers=n_buffers)

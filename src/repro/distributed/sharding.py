@@ -157,24 +157,37 @@ def kernel_heads_shard(n_kv_heads: int) -> bool:
     return n_kv_heads % model_axis_size(mesh) == 0
 
 
-def over_heads(fn, head_args: Sequence[bool]):
-    """Run a Pallas kernel once per `model` shard, on that shard's heads.
+def over_heads(fn, arg_axes: Sequence[str]):
+    """Run a Pallas kernel once per mesh shard, on that shard's slots and
+    heads.
 
     GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
-    automatically partitioned"). Attention heads are independent, so a
-    shard_map that hands each shard its own slice of axis 1 — of every
-    argument flagged in ``head_args`` and of the output — computes exactly
-    the unsharded result; other arguments (block tables, lengths) are
-    replicated. With GQA the query and KV head slices line up whenever
-    :func:`kernel_heads_shard` holds. Identity without a multi-device
-    mesh."""
+    automatically partitioned"). Attention is independent per slot and per
+    head, so a shard_map that hands each shard its own slice computes
+    exactly the unsharded result. ``arg_axes`` names, per argument, which
+    of its leading axes split: ``"b"`` splits axis 0 (the slot batch) over
+    :data:`BATCH`, ``"h"`` splits axis 1 (heads) over `model`, ``"bh"``
+    both. The output is ``"bh"``. An axis that does not
+    divide its mesh axes stays whole (:func:`fix_spec_for`). With GQA the
+    query and KV head slices line up whenever :func:`kernel_heads_shard`
+    holds. Identity without a multi-device mesh."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.size <= 1:
         return fn
-    head = P(None, MODEL_AXIS) if MODEL_AXIS in mesh.axis_names else P()
-    return jax.shard_map(fn, in_specs=tuple(head if h else P()
-                                            for h in head_args),
-                         out_specs=head, check_vma=False)
+
+    def spec(axes: str, shape) -> P:
+        base = (BATCH if "b" in axes else None,
+                MODEL_AXIS if "h" in axes else None)
+        return fix_spec_for(mesh, P(*base[:len(shape)]), shape)
+
+    def run(*args):
+        in_specs = tuple(spec(a, x.shape) for a, x in zip(arg_axes, args))
+        out = jax.eval_shape(fn, *args)
+        return jax.shard_map(fn, in_specs=in_specs,
+                             out_specs=spec("bh", out.shape),
+                             check_vma=False)(*args)
+
+    return run
 
 
 # ---------------------------------------------------------------------------

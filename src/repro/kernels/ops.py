@@ -126,7 +126,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _flash, plan=eff, causal=causal, window=window, scale=scale,
         q_offset=q_offset, kv_len=skv if kp.shape[2] != skv else None,
         interpret=not on_tpu())
-    out = shd.over_heads(flash, (True, True, True))(qp, kp, vp)
+    out = shd.over_heads(flash, ("bh", "bh", "bh"))(qp, kp, vp)
     return out[:, :, :sq]
 
 

@@ -303,7 +303,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     if use_pallas and causal:
         walk = functools.partial(paged_flash_decode, window=window,
                                  interpret=not on_tpu)
-        return over_heads(walk, (True, True, True, False, False))(
+        return over_heads(walk, ("bh", "h", "h", "b", "b"))(
             q, k_pages, v_pages, block_tables, cache_len)
     k = gather_kv_pages(k_pages, block_tables, seq_axis=1)
     v = gather_kv_pages(v_pages, block_tables, seq_axis=1)

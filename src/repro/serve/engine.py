@@ -206,6 +206,11 @@ class EngineCore:
     equivalence matrix's bit-identity cells reuse compilations across
     modes. The core is deliberately thin: it never looks at a scheduler
     and runs no serve loop.
+
+    Every jitted step that takes the pool donates it: the serve loops
+    thread the pool through the steps functionally, so a step writes its
+    output into the argument's buffers and holds one pool, not two. A pool
+    passed to a step must not be used again.
     """
 
     def __init__(self, model: Model, params: Any, ecfg: EngineConfig):
@@ -384,7 +389,7 @@ class PrefillRole:
                 n_gen=pool.n_gen.at[slot].set(1),
                 budget=pool.budget.at[slot].set(budget)), first
 
-        return jax.jit(run)
+        return jax.jit(run, donate_argnums=5)
 
     def admit_into_slot(self, pool: PoolState, slot: int,
                         prompt: np.ndarray, max_new_tokens: int
@@ -429,7 +434,7 @@ class PrefillRole:
                 n_gen=pool.n_gen.at[slot].set(1),
                 budget=pool.budget.at[slot].set(budget)), first
 
-        return jax.jit(run)
+        return jax.jit(run, donate_argnums=6)
 
     def paged_admit(self, pool: PoolState, slot: int,
                     req: sched_mod.Request, geom: sched_mod.PageGeometry
@@ -486,7 +491,7 @@ class PrefillRole:
                 n_gen=pool.n_gen.at[slot].set(1),
                 budget=pool.budget.at[slot].set(budget)), first
 
-        return jax.jit(run)
+        return jax.jit(run, donate_argnums=7)
 
     def shared_paged_admit(self, pool: PoolState, slot: int,
                            req: sched_mod.Request,
@@ -581,7 +586,7 @@ class PrefillRole:
                 n_gen=pool.n_gen.at[slot].set(1),
                 budget=pool.budget.at[slot].set(budget)), first
 
-        return jax.jit(run)
+        return jax.jit(run, donate_argnums=8)
 
     def exec_prefill_chunk(self, pool: PoolState,
                            step: sched_mod.PrefillStep,
@@ -659,7 +664,7 @@ class PrefillRole:
                 n_gen=pool.n_gen.at[slot].set(1),
                 budget=pool.budget.at[slot].set(budget)), first
 
-        return jax.jit(run)
+        return jax.jit(run, donate_argnums=6)
 
     def exec_dense_chunk(self, pool: PoolState, step: sched_mod.PrefillStep
                          ) -> Tuple[PoolState, jax.Array]:
@@ -753,7 +758,7 @@ class DecodeRole:
                 pool, (toks, valid) = jax.lax.scan(step, pool, None, length=n)
                 return pool, toks, valid        # (n, S) each
 
-            core._pool_chunk_fns[n] = jax.jit(run)
+            core._pool_chunk_fns[n] = jax.jit(run, donate_argnums=1)
         return core._pool_chunk_fns[n]
 
     # ------------------------------------------- speculative verify chunk
@@ -796,7 +801,7 @@ class DecodeRole:
                                 block_tables=pool.block_tables)
                 return new, toks.astype(jnp.int32).T, fold.valid.T
 
-            core._verify_fns[k] = jax.jit(run)
+            core._verify_fns[k] = jax.jit(run, donate_argnums=1)
         return core._verify_fns[k]
 
     def build_drafts(self, sch: sched_mod.Scheduler, k: int

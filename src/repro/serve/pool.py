@@ -45,25 +45,29 @@ DECODE_ROLE = sched_mod.DECODE_ROLE
 
 # ----------------------------------------------------- device pool budget
 
-#: How many copies of the layer-0 pool the jitted steps hold at once: the
-#: argument, the output (the steps do not donate the pool), and the decode
-#: scan's carry. Read off ``memory_analysis()`` of the pooled decode chunk
-#: at qwen2.5-3b widths on a v5e: output = pool, temp ~= pool + 1 GB.
-POOL_STEP_COPIES = 3
+#: How many copies of the layer-0 pool the costliest jitted step holds at
+#: once. The steps donate the pool, so argument and output share buffers;
+#: the decode chunk's scan over the stacked layers still builds the updated
+#: caches beside the old ones (``memory_analysis()`` of the pooled decode
+#: chunk at qwen2.5-3b widths on a v5e: temp = pool + 0.57 GB).
+#: tests/test_chip_compile.py holds the compiled steps to this count.
+POOL_STEP_COPIES = 2
+
+#: Share of the bytes left beside the weights that the pool tiers take; the
+#: rest is for the steps' activations.
+POOL_FRACTION = 0.8
 
 
-def pool_bytes_beside(params: Any, *, target=None, fraction: float = 0.8,
-                      layer1_fraction: Optional[float] = None
-                      ) -> Tuple[int, int]:
+def pool_bytes_beside(params: Any, *, target=None) -> Tuple[int, int]:
     """Per-device ``(layer0_bytes, layer1_bytes)`` that fit beside
     ``params`` on the first device.
 
     The device's ``bytes_limit`` (the target's pool-level capacity where the
     backend reports none) less the bytes of ``params`` that live on it
-    leaves ``free``. ``fraction`` of it goes to the pool — the rest is for
-    the steps' activations — split between the tiers in the ratio
-    ``1 : layer1_fraction`` (the scheduler's die-split default) after
-    charging layer 0 :data:`POOL_STEP_COPIES` times. Pass the result as
+    leaves ``free``. :data:`POOL_FRACTION` of it goes to the pool, split
+    between the tiers in the scheduler's die-split ratio
+    (``default_layer1_fraction``) after charging layer 0
+    :data:`POOL_STEP_COPIES` times. Pass the result as
     ``derive_page_geometry(layer0_bytes=..., layer1_bytes=...)``: both are
     per-shard budgets, as that function expects."""
     target = target or get_target()
@@ -76,9 +80,8 @@ def pool_bytes_beside(params: Any, *, target=None, fraction: float = 0.8,
     if free <= 0:
         raise ValueError(f"the weights take {weights} B of the device's "
                          f"{limit} B; nothing is left for the KV pool")
-    if layer1_fraction is None:
-        layer1_fraction = sched_mod.default_layer1_fraction(target)
-    layer0 = int(fraction * free / (POOL_STEP_COPIES + layer1_fraction))
+    layer1_fraction = sched_mod.default_layer1_fraction(target)
+    layer0 = int(POOL_FRACTION * free / (POOL_STEP_COPIES + layer1_fraction))
     return layer0, int(layer0 * layer1_fraction)
 
 
@@ -193,13 +196,13 @@ class PoolManager:
         def make():
             state = {"caches": transformer.init_caches(cfg, n_slots,
                                                        self.ecfg.max_len)}
-            zeros = jnp.zeros((n_slots,), jnp.int32)
+            zeros = lambda: jnp.zeros((n_slots,), jnp.int32)
             return PoolState(
                 state=state,
                 tok=jnp.full((n_slots,), self.ecfg.pad_token, jnp.int32),
-                cache_len=zeros,
+                cache_len=zeros(),
                 done=jnp.ones((n_slots,), bool),
-                n_gen=zeros, budget=zeros)
+                n_gen=zeros(), budget=zeros())
 
         return self._build(make)
 
@@ -238,12 +241,12 @@ class PoolManager:
             spill = transformer.init_paged_caches(
                 cfg, geom.n_spill_pages, geom.n_spill_pages,
                 geom.page_tokens, dtype=l1.dtype, quant_scales=l1.scaled)
-            zeros = jnp.zeros((n_slots,), jnp.int32)
+            zeros = lambda: jnp.zeros((n_slots,), jnp.int32)
             pool = PoolState(
                 state=state,
                 tok=jnp.full((n_slots,), self.ecfg.pad_token, jnp.int32),
-                cache_len=zeros, done=jnp.ones((n_slots,), bool),
-                n_gen=zeros, budget=zeros,
+                cache_len=zeros(), done=jnp.ones((n_slots,), bool),
+                n_gen=zeros(), budget=zeros(),
                 block_tables=jnp.zeros((n_slots, geom.max_pages_per_slot),
                                        jnp.int32))
             return pool, spill
@@ -267,6 +270,9 @@ class PoolManager:
         policy's fp8 layer 0 <-> int8 layer 1) dequantize each moved page
         to f32 and re-encode at the destination codec, writing fresh
         per-page scales when the destination is scaled.
+
+        The destination tier is donated: the copy writes the moved pages
+        in place instead of copying the whole tier.
         """
         key = (src_codec, dst_codec)
         if key in self._tier_copy:
@@ -321,7 +327,7 @@ class PoolManager:
                 out.setdefault(gname, {})[gkey] = leaf
             return out
 
-        self._tier_copy[key] = jax.jit(copy)
+        self._tier_copy[key] = jax.jit(copy, donate_argnums=1)
         return self._tier_copy[key]
 
     def _tier_codecs(self) -> Tuple[str, str]:

@@ -4,6 +4,10 @@ Kernels execute in interpret mode on CPU (the kernel body runs in Python);
 the oracles in kernels/ref.py are the ground truth.
 """
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -236,3 +240,23 @@ def test_ops_attention_grad_flows():
 
     g = jax.grad(f)(q)
     assert bool(jnp.isfinite(g).all()) and float(jnp.abs(g).max()) > 0
+
+
+@pytest.mark.parametrize("mesh", ["2x2", "1x4"])
+def test_kernels_split_over_slots_and_heads_under_a_mesh(mesh):
+    """Under a data x model mesh the Pallas flash kernel and page walk run
+    per shard on that shard's slots and heads, gather nothing, and equal
+    the unsharded result (tests/over_heads_check.py, in a child python on
+    four forced host devices)."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4").strip()
+    env["PYTHONPATH"] = (os.path.join(os.path.dirname(here), "src")
+                         + os.pathsep + env.get("PYTHONPATH", "")
+                         ).rstrip(os.pathsep)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(here, "over_heads_check.py"), mesh],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-2000:])
+    assert "OVER_HEADS_OK" in proc.stdout

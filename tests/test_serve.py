@@ -275,12 +275,14 @@ def test_engine_tokens_unchanged_by_the_serving_cast():
 
 def test_pool_bytes_beside_leaves_room_for_the_weights():
     from repro.core.target import get_target
-    from repro.serve.pool import POOL_STEP_COPIES, pool_bytes_beside
+    from repro.serve.pool import (POOL_FRACTION, POOL_STEP_COPIES,
+                                  pool_bytes_beside)
     params = build_model(TINY).init(jax.random.PRNGKey(0))
     target = get_target("tpu-v5e")
     hbm = target.hierarchy.level("hbm").capacity_bytes
     weights = sum(a.nbytes for a in jax.tree.leaves(params))
     l0, l1 = pool_bytes_beside(params, target=target)
     assert l1 == int(l0 * 0.5)                 # the TPU die-split ratio
-    assert POOL_STEP_COPIES * l0 + l1 <= 0.8 * (hbm - weights)
-    assert POOL_STEP_COPIES * l0 + l1 >= 0.8 * (hbm - weights) - 4
+    budget = POOL_FRACTION * (hbm - weights)
+    assert POOL_STEP_COPIES * l0 + l1 <= budget
+    assert POOL_STEP_COPIES * l0 + l1 >= budget - 4

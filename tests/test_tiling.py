@@ -42,9 +42,11 @@ def test_clamped_attention_blocks_stay_aligned():
 def test_plan_matmul_grows_with_capacity():
     """The paper's law: more scratchpad => bigger tiles => fewer reloads."""
     small = TpuProfile(name="small", peak_flops_bf16=1, hbm_bw=1, hbm_bytes=1,
-                       ici_link_bw=1, ici_links=1, vmem_bytes=8 * MiB)
+                       ici_link_bw=1, ici_links=1, vmem_bytes=8 * MiB,
+                       vmem_limit_bytes=8 * MiB)
     big = TpuProfile(name="big", peak_flops_bf16=1, hbm_bw=1, hbm_bytes=1,
-                     ici_link_bw=1, ici_links=1, vmem_bytes=128 * MiB)
+                     ici_link_bw=1, ici_links=1, vmem_bytes=128 * MiB,
+                     vmem_limit_bytes=128 * MiB)
     p_small = tiling.plan_matmul(8192, 8192, 8192, profile=small)
     p_big = tiling.plan_matmul(8192, 8192, 8192, profile=big)
     assert p_big.bm * p_big.bn > p_small.bm * p_small.bn
@@ -69,10 +71,10 @@ def test_plan_matmul_properties(m, k, n, vmem_mib):
     exceed the (aligned-up) problem dims."""
     prof = TpuProfile(name="p", peak_flops_bf16=1, hbm_bw=1, hbm_bytes=1,
                       ici_link_bw=1, ici_links=1,
-                      vmem_bytes=vmem_mib * MiB)
+                      vmem_bytes=128 * MiB, vmem_limit_bytes=vmem_mib * MiB)
     plan = tiling.plan_matmul(m, k, n, profile=prof)
     assert plan.bm % 128 == 0 and plan.bk % 128 == 0 and plan.bn % 128 == 0
-    assert plan.vmem_bytes() <= prof.vmem_bytes * 0.75
+    assert plan.vmem_bytes() <= prof.vmem_limit_bytes * 0.75
     assert plan.bm <= max(128, -(-m // 128) * 128)
     assert plan.bn <= max(128, -(-n // 128) * 128)
     assert plan.bk <= max(128, -(-k // 128) * 128)
