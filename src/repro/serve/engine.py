@@ -70,7 +70,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 import jax
@@ -80,6 +79,7 @@ import numpy as np
 from repro.distributed import sharding as shd
 from repro.models.api import Model
 from repro.serve import scheduler as sched_mod
+from repro.serve import spans
 from repro.serve import speculate as spec_mod
 from repro.serve.pool import (DECODE_ROLE, PREFILL_ROLE, PoolManager,
                               PoolState)
@@ -238,6 +238,7 @@ class EngineCore:
         self._chunk_prefill_fns: Dict[Any, Any] = {}        # paged
         self._dense_chunk_prefill_fns: Dict[Any, Any] = {}  # dense
         self.last_stats: Dict[str, Any] = {}
+        self.last_phase: Optional[spans.Span] = None   # set by _timed
 
     def _has_ssm(self) -> bool:
         return any(kind.attn == "mamba"
@@ -283,24 +284,29 @@ class EngineCore:
             by[role] = by.get(role, 0) + 1
         return jax.device_get(tree)
 
-    def _timed(self, phase: str, fn, *args, role: Optional[str] = None):
-        """Run ``fn`` and, in ``phase_timing`` mode, charge its wall time
-        (blocked on device completion) to ``last_stats['phase_s'][phase]``
-        — and, when ``role`` is given, to ``last_stats['role_s'][role]``
-        (the disaggregated per-role busy breakdown). Off by default:
-        blocking per phase would serialize the dispatch pipeline the serve
-        loop overlaps."""
-        if not self.ecfg.phase_timing:
-            return fn(*args)
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        acc = self.last_stats.setdefault("phase_s", {})
-        acc[phase] = acc.get(phase, 0.0) + dt
+    def _timed(self, phase: str, fn, *args, role: Optional[str] = None,
+               **span_args):
+        """Run ``fn`` inside the span ``engine.<phase>`` (``span_args``, and
+        ``role`` when given, are its args; :mod:`repro.serve.spans`) and
+        keep that span as ``last_phase``. In ``phase_timing`` mode the span
+        closes only after the device work is done, and its wall time is
+        charged to ``last_stats['phase_s'][phase]`` — and, when ``role`` is
+        given, to ``last_stats['role_s'][role]`` (the disaggregated
+        per-role busy breakdown). Off by default: blocking per phase would
+        serialize the dispatch pipeline the serve loop overlaps."""
         if role is not None:
-            racc = self.last_stats.setdefault("role_s", {})
-            racc[role] = racc.get(role, 0.0) + dt
+            span_args["role"] = role
+        with spans.span("engine." + phase, **span_args) as sp:
+            out = fn(*args)
+            if self.ecfg.phase_timing:
+                jax.block_until_ready(out)
+        self.last_phase = sp
+        if self.ecfg.phase_timing:
+            acc = self.last_stats.setdefault("phase_s", {})
+            acc[phase] = acc.get(phase, 0.0) + sp.seconds
+            if role is not None:
+                racc = self.last_stats.setdefault("role_s", {})
+                racc[role] = racc.get(role, 0.0) + sp.seconds
         return out
 
     # --------------------------------------------------------- bucketing
@@ -1128,148 +1134,183 @@ class Engine:
         n = self.ecfg.sync_interval
         p_max = geom.max_pages_per_slot
         while sch.has_work():
-            t0 = time.perf_counter()
-            # a speculative boundary advances a slot by up to k+1 tokens in
-            # its one verify forward, so page growth is planned for k+1
-            plan = sch.plan_boundary(
-                chunk_tokens=(spec_k + 1 if spec_k else n),
-                max_len=self.ecfg.max_len)
-            for req in plan.rejects:
-                req.finish_step = step_clock
-            # spills FIRST: they read layer-0 pages that restores/admits may
-            # reuse later this boundary (functional arrays keep this exact)
-            for act in plan.spills:
-                spill = core._timed(
-                    "insert", pools.exec_spill, pool, spill, act, p_max,
-                    role=self._owner_role(act.req) if disagg else None)
-            for act in plan.restores:
-                role = self._owner_role(act.req) if disagg else None
-                if disagg:
-                    pools.claim(act.slot, role)
-                pool = core._timed("insert", pools.exec_restore,
-                                   pool, spill, act, p_max, role=role)
-            # layer-2 resumes BEFORE admissions/prefill chunks: a resumed
-            # session's pages were registered in the prefix index at plan
-            # time, so their bytes must be resident before any same-
-            # boundary matcher's suffix chunk reads them
-            for rs in plan.resumes:
-                if disagg:
-                    pools.claim(rs.slot, DECODE_ROLE)
-                pool = core._timed("insert", self._exec_resume, pool, rs,
-                                   geom, role=dec_role)
-            for slot, req in plan.admits:
-                req.admit_step = step_clock
-                if disagg:
-                    pools.claim(slot, PREFILL_ROLE)
-                if req.prefill_pos >= 0:
-                    continue    # chunked admission: runs via prefill_steps
-                if req.prefix_len:      # prefix-index hit: suffix-only prefill
+            with spans.span("engine.boundary",
+                            index=self.last_stats["chunks"]) as bsp:
+                syncs0 = self.last_stats["host_syncs"]
+                # a speculative boundary advances a slot by up to k+1
+                # tokens in its one verify forward, so page growth is
+                # planned for k+1
+                plan = sch.plan_boundary(
+                    chunk_tokens=(spec_k + 1 if spec_k else n),
+                    max_len=self.ecfg.max_len)
+                for req in plan.rejects:
+                    req.finish_step, req.finish_t = step_clock, spans.now()
+                # spills FIRST: they read layer-0 pages that restores/admits
+                # may reuse later this boundary (functional arrays keep this
+                # exact)
+                for act in plan.spills:
+                    spill = core._timed(
+                        "insert", pools.exec_spill, pool, spill, act, p_max,
+                        role=self._owner_role(act.req) if disagg else None,
+                        kind="spill", rid=act.req.rid)
+                for act in plan.restores:
+                    role = self._owner_role(act.req) if disagg else None
+                    if disagg:
+                        pools.claim(act.slot, role)
+                    pool = core._timed("insert", pools.exec_restore,
+                                       pool, spill, act, p_max, role=role,
+                                       kind="restore", rid=act.req.rid)
+                # layer-2 resumes BEFORE admissions/prefill chunks: a resumed
+                # session's pages were registered in the prefix index at
+                # plan time, so their bytes must be resident before any
+                # same-boundary matcher's suffix chunk reads them
+                for rs in plan.resumes:
+                    if disagg:
+                        pools.claim(rs.slot, DECODE_ROLE)
+                    pool = core._timed("insert", self._exec_resume, pool, rs,
+                                       geom, role=dec_role, kind="resume",
+                                       rid=rs.req.rid)
+                for slot, req in plan.admits:
+                    req.admit_step = step_clock
+                    if disagg:
+                        pools.claim(slot, PREFILL_ROLE)
+                    if req.prefill_pos >= 0:
+                        continue    # chunked admission: runs via prefill_steps
+                    tokens = req.prompt_len - req.prefix_len
+                    if req.prefix_len:  # prefix-index hit: suffix-only prefill
+                        pool, first = core._timed(
+                            "prefill", pre.shared_paged_admit,
+                            pool, slot, req, geom, role=pre_role,
+                            rid=req.rid, tokens=tokens)
+                    else:
+                        pool, first = core._timed(
+                            "prefill", pre.paged_admit, pool, slot, req,
+                            geom, role=pre_role, rid=req.rid, tokens=tokens)
+                    req.status = sched_mod.DECODING
+                    pending_first.append((req, first))
+                # chunk prefills AFTER every copy, in plan order (scheduler's
+                # ordering contract); a final chunk arms its slot like an
+                # admit
+                for step in plan.prefill_steps:
                     pool, first = core._timed(
-                        "prefill", pre.shared_paged_admit,
-                        pool, slot, req, geom, role=pre_role)
-                else:
-                    pool, first = core._timed("prefill", pre.paged_admit,
-                                              pool, slot, req, geom,
-                                              role=pre_role)
-                req.status = sched_mod.DECODING
-                pending_first.append((req, first))
-            # chunk prefills AFTER every copy, in plan order (scheduler's
-            # ordering contract); a final chunk arms its slot like an admit
-            for step in plan.prefill_steps:
-                pool, first = core._timed("prefill", pre.exec_prefill_chunk,
-                                          pool, step, geom, role=pre_role)
-                if step.final:
-                    step.req.status = sched_mod.DECODING
-                    pending_first.append((step.req, first))
-            # page handover: each request whose prompt completed this
-            # boundary moves prefill -> decode by a zero-copy ownership
-            # flip; the decode role's table upload below carries its row
-            for h in plan.handovers:
-                core._timed("handover", pools.transfer_ownership,
-                            h.slot, h.pages)
-            # the boundary's page moves, as one host->device upload; the
-            # decode role uploads only the rows it owns (handover is what
-            # makes a row appear)
-            pool = dataclasses.replace(pool, block_tables=jnp.asarray(
-                sch.block_table(role=DECODE_ROLE) if disagg
-                else sch.block_table()))
-            t_dec = time.perf_counter()
-            if spec_k:
-                # one verify forward replaces the sync_interval-step scan;
-                # the boundary still costs exactly one host sync below
-                drafts, dlen = dec.build_drafts(sch, spec_k)
-                pool, toks, valid = core._timed(
-                    "generate", dec.verify_fn(spec_k), core.params, pool,
-                    jnp.asarray(drafts), jnp.asarray(dlen), role=dec_role)
-                step_clock += 1
-                self.last_stats["decode_steps"] += 1
-                self.last_stats["spec_proposed"] += int(dlen.sum())
-            else:
-                pool, toks, valid = core._timed(
-                    "generate", dec.pool_chunk(n), core.params, pool,
-                    role=dec_role)
-                step_clock += n
-                self.last_stats["decode_steps"] += n
-            self.last_stats["chunks"] += 1
-            # ---- drain boundary: ONE host sync per role (decode always;
-            # prefill only on boundaries that completed a prompt)
-            if disagg:
-                toks_h, valid_h, done_h = core._timed(
-                    "drain", core._fetch, (toks, valid, pool.done),
-                    DECODE_ROLE, role=DECODE_ROLE)
-                boundary_decode_wall.append(time.perf_counter() - t_dec)
-                firsts = []
-                if pending_first:
-                    firsts = core._timed(
-                        "drain", core._fetch,
-                        [f for _, f in pending_first], PREFILL_ROLE,
-                        role=PREFILL_ROLE)
-            else:
-                toks_h, valid_h, done_h, firsts = core._timed(
-                    "drain", core._fetch,
-                    (toks, valid, pool.done, [f for _, f in pending_first]))
-            emitted = len(firsts)
-            for (req, _), f in zip(pending_first, firsts):
-                req.tokens.append(int(f))
-                # the first token becomes real only at THIS drain — the
-                # boundary clock has already advanced past the decode/verify
-                # work, so ttft_emit_steps measures true first-token
-                # availability instead of the admission-time clock (which is
-                # 0 for anything admitted at the first boundary)
-                req.first_step = step_clock
-            pending_first.clear()
-            for slot in sorted(sch.active):
-                req = sch.active[slot]
-                before = len(req.tokens)
-                req.tokens.extend(
-                    int(t) for t, v in zip(toks_h[:, slot], valid_h[:, slot])
-                    if v)
-                got = len(req.tokens) - before
-                emitted += got
-                if disagg:
-                    self.last_stats["decode_tokens"] += got
+                        "prefill", pre.exec_prefill_chunk, pool, step, geom,
+                        role=pre_role, rid=step.req.rid,
+                        tokens=step.n_tokens)
+                    if step.final:
+                        step.req.status = sched_mod.DECODING
+                        pending_first.append((step.req, first))
+                # page handover: each request whose prompt completed this
+                # boundary moves prefill -> decode by a zero-copy ownership
+                # flip; the decode role's table upload below carries its row
+                for h in plan.handovers:
+                    core._timed("handover", pools.transfer_ownership,
+                                h.slot, h.pages, rid=h.req.rid)
+                # the boundary's page moves, as one host->device upload; the
+                # decode role uploads only the rows it owns (handover is
+                # what makes a row appear)
+                with spans.span("engine.upload"):
+                    pool = dataclasses.replace(pool, block_tables=jnp.asarray(
+                        sch.block_table(role=DECODE_ROLE) if disagg
+                        else sch.block_table()))
+                frontier = {slot: req.cache_len
+                            for slot, req in sch.active.items()
+                            if req.status == sched_mod.DECODING}
                 if spec_k:
-                    # a live slot's boundary emission is accepted drafts + 1
-                    # correction token; just-admitted slots (dlen=0) emit
-                    # exactly 1 and contribute 0 accepted
-                    self.last_stats["spec_accepted"] += max(got - 1, 0)
-                # a mid-prefill slot's device done flag is still the free
-                # marker from before its admission — only DECODING slots
-                # can drain
-                if done_h[slot] and req.status != sched_mod.PREFILLING:
-                    req.finish_step = step_clock
-                    pools.release(slot)
-                    sch.complete(slot)
-            boundary_wall.append(time.perf_counter() - t0)
+                    # one verify forward replaces the sync_interval-step
+                    # scan; the boundary still costs exactly one host sync
+                    drafts, dlen = dec.build_drafts(sch, spec_k)
+                    pool, toks, valid = core._timed(
+                        "generate", dec.verify_fn(spec_k), core.params, pool,
+                        jnp.asarray(drafts), jnp.asarray(dlen),
+                        role=dec_role)
+                    step_clock += 1
+                    self.last_stats["decode_steps"] += 1
+                    self.last_stats["spec_proposed"] += int(dlen.sum())
+                else:
+                    pool, toks, valid = core._timed(
+                        "generate", dec.pool_chunk(n), core.params, pool,
+                        role=dec_role)
+                    step_clock += n
+                    self.last_stats["decode_steps"] += n
+                t_generate = core.last_phase.start
+                self.last_stats["chunks"] += 1
+                # ---- drain boundary: ONE host sync per role (decode always;
+                # prefill only on boundaries that completed a prompt)
+                if disagg:
+                    toks_h, valid_h, done_h = core._timed(
+                        "drain", core._fetch, (toks, valid, pool.done),
+                        DECODE_ROLE, role=DECODE_ROLE)
+                    boundary_decode_wall.append(
+                        core.last_phase.end - t_generate)
+                    firsts = []
+                    if pending_first:
+                        firsts = core._timed(
+                            "drain", core._fetch,
+                            [f for _, f in pending_first], PREFILL_ROLE,
+                            role=PREFILL_ROLE)
+                else:
+                    toks_h, valid_h, done_h, firsts = core._timed(
+                        "drain", core._fetch,
+                        (toks, valid, pool.done,
+                         [f for _, f in pending_first]))
+                t_drain = core.last_phase.end
+                with spans.span("engine.emit"):
+                    emitted = len(firsts)
+                    for (req, _), f in zip(pending_first, firsts):
+                        req.tokens.append(int(f))
+                        # the first token becomes real only at THIS drain —
+                        # the boundary clock has already advanced past the
+                        # decode/verify work, so ttft_emit_steps measures
+                        # true first-token availability instead of the
+                        # admission-time clock (which is 0 for anything
+                        # admitted at the first boundary)
+                        req.first_step, req.first_t = step_clock, t_drain
+                    pending_first.clear()
+                    decode = []
+                    for slot in sorted(sch.active):
+                        req = sch.active[slot]
+                        before = len(req.tokens)
+                        req.tokens.extend(
+                            int(t) for t, v in zip(toks_h[:, slot],
+                                                   valid_h[:, slot]) if v)
+                        got = len(req.tokens) - before
+                        emitted += got
+                        if slot in frontier:
+                            decode.append((frontier[slot], got))
+                        if disagg:
+                            self.last_stats["decode_tokens"] += got
+                        if spec_k:
+                            # a live slot's boundary emission is accepted
+                            # drafts + 1 correction token; just-admitted
+                            # slots (dlen=0) emit exactly 1 and contribute 0
+                            # accepted
+                            self.last_stats["spec_accepted"] += max(got - 1,
+                                                                    0)
+                        # a mid-prefill slot's device done flag is still the
+                        # free marker from before its admission — only
+                        # DECODING slots can drain
+                        if done_h[slot] and req.status != sched_mod.PREFILLING:
+                            req.finish_step, req.finish_t = step_clock, t_drain
+                            pools.release(slot)
+                            sch.complete(slot)
+                bsp.args.update(
+                    admitted=[req.prompt_len for _, req in plan.admits],
+                    prefill_tokens=sum(s.n_tokens
+                                       for s in plan.prefill_steps),
+                    decode=decode, emitted=emitted,
+                    spills=len(plan.spills), restores=len(plan.restores),
+                    host_syncs=self.last_stats["host_syncs"] - syncs0)
+            boundary_wall.append(bsp.seconds)
             boundary_tokens.append(emitted)
             if max_steps is not None and step_clock >= max_steps:
                 break
         self.last_stats["boundary_wall_s"] = boundary_wall
         self.last_stats["boundary_tokens"] = boundary_tokens
         if disagg:
-            # decode-role boundary wall: decode dispatch + its drain only
-            # (meaningful under phase_timing, where the prefill phase has
-            # blocked before t_dec) — the inter-token clock a decode
+            # decode-role boundary wall: start of engine.generate to the end
+            # of the decode role's engine.drain (meaningful under
+            # phase_timing, where the prefill phase has blocked before
+            # engine.generate opens) — the inter-token clock a decode
             # consumer experiences when prefill runs on its own engine
             self.last_stats["boundary_decode_wall_s"] = boundary_decode_wall
         self._finish_spec_stats()
@@ -1340,79 +1381,101 @@ class Engine:
         boundary_tokens: List[int] = []
         step_clock = 0
         while sch.has_work():
-            t0 = time.perf_counter()
-            for slot, req in sch.admit():
-                req.admit_step = step_clock
-                if req.prompt_len > self.ecfg.max_len:
-                    # reject cleanly: one bad request must not abort the
-                    # stream or leak its slot
-                    req.finish_step = step_clock
-                    sch.complete(slot, status=sched_mod.REJECTED)
-                    continue
-                if chunked:
-                    continue    # prefills by chunks via plan_prefill below
-                pool, first = core._timed(
-                    "prefill", pre.admit_into_slot,
-                    pool, slot, req.prompt, req.max_new_tokens)
-                req.status = sched_mod.DECODING
-                pending_first.append((req, first))
-            if chunked:
-                for step in sch.plan_prefill():
+            with spans.span("engine.boundary",
+                            index=self.last_stats["chunks"]) as bsp:
+                syncs0 = self.last_stats["host_syncs"]
+                admits = sch.admit()
+                for slot, req in admits:
+                    req.admit_step = step_clock
+                    if req.prompt_len > self.ecfg.max_len:
+                        # reject cleanly: one bad request must not abort the
+                        # stream or leak its slot
+                        req.finish_step, req.finish_t = step_clock, spans.now()
+                        sch.complete(slot, status=sched_mod.REJECTED)
+                        continue
+                    if chunked:
+                        continue    # prefills by chunks via plan_prefill below
                     pool, first = core._timed(
-                        "prefill", pre.exec_dense_chunk, pool, step)
+                        "prefill", pre.admit_into_slot,
+                        pool, slot, req.prompt, req.max_new_tokens,
+                        rid=req.rid, tokens=req.prompt_len)
+                    req.status = sched_mod.DECODING
+                    pending_first.append((req, first))
+                steps = sch.plan_prefill() if chunked else []
+                for step in steps:
+                    pool, first = core._timed(
+                        "prefill", pre.exec_dense_chunk, pool, step,
+                        rid=step.req.rid, tokens=step.n_tokens)
                     if step.final:
                         step.req.status = sched_mod.DECODING
                         pending_first.append((step.req, first))
-            if spec_k:
-                # one verify forward replaces the sync_interval-step scan;
-                # the boundary still costs exactly one host sync below
-                drafts, dlen = dec.build_drafts(sch, spec_k)
-                pool, toks, valid = core._timed(
-                    "generate", dec.verify_fn(spec_k), core.params, pool,
-                    jnp.asarray(drafts), jnp.asarray(dlen))
-                step_clock += 1
-                self.last_stats["decode_steps"] += 1
-                self.last_stats["spec_proposed"] += int(dlen.sum())
-            else:
-                n = self.ecfg.sync_interval
-                pool, toks, valid = core._timed(
-                    "generate", dec.pool_chunk(n), core.params, pool)
-                step_clock += n
-                self.last_stats["decode_steps"] += n
-            self.last_stats["chunks"] += 1
-            # ---- drain boundary: the single host sync of this iteration
-            toks_h, valid_h, done_h, firsts = core._timed(
-                "drain", core._fetch,
-                (toks, valid, pool.done, [f for _, f in pending_first]))
-            emitted = len(firsts)
-            for (req, _), f in zip(pending_first, firsts):
-                req.tokens.append(int(f))
-                # the first token becomes real only at THIS drain — the
-                # boundary clock has already advanced past the decode/verify
-                # work, so ttft_emit_steps measures true first-token
-                # availability instead of the admission-time clock (which is
-                # 0 for anything admitted at the first boundary)
-                req.first_step = step_clock
-            pending_first.clear()
-            for slot in sorted(sch.active):
-                req = sch.active[slot]
-                before = len(req.tokens)
-                req.tokens.extend(
-                    int(t) for t, v in zip(toks_h[:, slot], valid_h[:, slot])
-                    if v)
-                got = len(req.tokens) - before
-                emitted += got
+                frontier = {slot: req.cache_len
+                            for slot, req in sch.active.items()
+                            if req.status == sched_mod.DECODING}
                 if spec_k:
-                    # a live slot's boundary emission is accepted drafts + 1
-                    # correction token; just-admitted slots (dlen=0) emit
-                    # exactly 1 and contribute 0 accepted
-                    self.last_stats["spec_accepted"] += max(got - 1, 0)
-                # mid-prefill slots keep their stale free-marker done flag;
-                # only DECODING slots can drain
-                if done_h[slot] and req.status != sched_mod.PREFILLING:
-                    req.finish_step = step_clock
-                    sch.complete(slot)
-            boundary_wall.append(time.perf_counter() - t0)
+                    # one verify forward replaces the sync_interval-step
+                    # scan; the boundary still costs exactly one host sync
+                    drafts, dlen = dec.build_drafts(sch, spec_k)
+                    pool, toks, valid = core._timed(
+                        "generate", dec.verify_fn(spec_k), core.params, pool,
+                        jnp.asarray(drafts), jnp.asarray(dlen))
+                    step_clock += 1
+                    self.last_stats["decode_steps"] += 1
+                    self.last_stats["spec_proposed"] += int(dlen.sum())
+                else:
+                    n = self.ecfg.sync_interval
+                    pool, toks, valid = core._timed(
+                        "generate", dec.pool_chunk(n), core.params, pool)
+                    step_clock += n
+                    self.last_stats["decode_steps"] += n
+                self.last_stats["chunks"] += 1
+                # ---- drain boundary: the single host sync of this iteration
+                toks_h, valid_h, done_h, firsts = core._timed(
+                    "drain", core._fetch,
+                    (toks, valid, pool.done, [f for _, f in pending_first]))
+                t_drain = core.last_phase.end
+                with spans.span("engine.emit"):
+                    emitted = len(firsts)
+                    for (req, _), f in zip(pending_first, firsts):
+                        req.tokens.append(int(f))
+                        # the first token becomes real only at THIS drain —
+                        # the boundary clock has already advanced past the
+                        # decode/verify work, so ttft_emit_steps measures
+                        # true first-token availability instead of the
+                        # admission-time clock (which is 0 for anything
+                        # admitted at the first boundary)
+                        req.first_step, req.first_t = step_clock, t_drain
+                    pending_first.clear()
+                    decode = []
+                    for slot in sorted(sch.active):
+                        req = sch.active[slot]
+                        before = len(req.tokens)
+                        req.tokens.extend(
+                            int(t) for t, v in zip(toks_h[:, slot],
+                                                   valid_h[:, slot]) if v)
+                        got = len(req.tokens) - before
+                        emitted += got
+                        if slot in frontier:
+                            decode.append((frontier[slot], got))
+                        if spec_k:
+                            # a live slot's boundary emission is accepted
+                            # drafts + 1 correction token; just-admitted
+                            # slots (dlen=0) emit exactly 1 and contribute 0
+                            # accepted
+                            self.last_stats["spec_accepted"] += max(got - 1,
+                                                                    0)
+                        # mid-prefill slots keep their stale free-marker done
+                        # flag; only DECODING slots can drain
+                        if done_h[slot] and req.status != sched_mod.PREFILLING:
+                            req.finish_step, req.finish_t = step_clock, t_drain
+                            sch.complete(slot)
+                bsp.args.update(
+                    admitted=[req.prompt_len for _, req in admits
+                              if req.status != sched_mod.REJECTED],
+                    prefill_tokens=sum(s.n_tokens for s in steps),
+                    decode=decode, emitted=emitted, spills=0, restores=0,
+                    host_syncs=self.last_stats["host_syncs"] - syncs0)
+            boundary_wall.append(bsp.seconds)
             boundary_tokens.append(emitted)
             if max_steps is not None and step_clock >= max_steps:
                 break

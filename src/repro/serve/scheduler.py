@@ -53,6 +53,7 @@ import numpy as np
 from repro.core.target import (CapacityPartition, HardwareTarget,
                                TieredPartition, get_target)
 from repro.models.config import ModelConfig
+from repro.serve import spans
 
 #: Request lifecycle states (DESIGN.md §Serving — slot lifecycle).
 QUEUED = "queued"
@@ -87,6 +88,13 @@ class Request:
     admit_step: int = -1
     first_step: int = -1                # first output token produced
     finish_step: int = -1
+    # the same four moments on the span recorder's clock (seconds of
+    # ``repro.serve.spans.now``): submitted, admitted by a plan, first token
+    # drained, slot completed (or rejected); -1 until stamped
+    submit_t: float = -1.0
+    admit_t: float = -1.0
+    first_t: float = -1.0
+    finish_t: float = -1.0
     # chunked prefill (DESIGN.md §Chunked prefill): how many prompt tokens
     # (including any shared prefix) have KV cached so far. -1 = unchunked
     # admission, which prefills the whole prompt in one boundary. The cursor
@@ -1006,6 +1014,7 @@ class Scheduler:
         req.rid = self._next_rid
         self._next_rid += 1
         req.status = QUEUED
+        req.submit_t = spans.now()
         self.queue.append(req)
         return req
 
@@ -1031,7 +1040,7 @@ class Scheduler:
                       prompt=np.asarray(prompt, np.int32),
                       max_new_tokens=int(max_new_tokens),
                       tokens=list(int(t) for t in tokens),
-                      submit_step=submit_step)
+                      submit_step=submit_step, submit_t=spans.now())
         self._next_rid += 1
         req.status = PARKED
         self.queue.append(req)
@@ -1057,17 +1066,19 @@ class Scheduler:
         :meth:`plan_boundary` (DESIGN.md §Paged two-tier pool).
         """
         placed: List[Tuple[int, Request]] = []
-        while self.queue and self.table.n_occupied < self.n_slots:
-            req = self._pop_next()
-            slot = self.table.allocate(req.rid)
-            req.status = PREFILLING
-            self.active[slot] = req
-            self.admit_order.append(req.rid)
-            self._active_order.append(slot)
-            placed.append((slot, req))
-        if self.chunk_prefill_tokens is None:
-            self.boundary_prefill_tokens.append(
-                sum(r.prompt_len for _, r in placed))
+        with spans.span("sched.plan"):
+            while self.queue and self.table.n_occupied < self.n_slots:
+                req = self._pop_next()
+                slot = self.table.allocate(req.rid)
+                req.status = PREFILLING
+                req.admit_t = spans.now()
+                self.active[slot] = req
+                self.admit_order.append(req.rid)
+                self._active_order.append(slot)
+                placed.append((slot, req))
+            if self.chunk_prefill_tokens is None:
+                self.boundary_prefill_tokens.append(
+                    sum(r.prompt_len for _, r in placed))
         return placed
 
     def plan_prefill(self) -> List[PrefillStep]:
@@ -1250,6 +1261,10 @@ class Scheduler:
         execute after all copies, in plan order.
         """
         assert self.pages is not None, "plan_boundary is paged-mode only"
+        with spans.span("sched.plan"):
+            return self._plan_boundary(chunk_tokens, max_len)
+
+    def _plan_boundary(self, chunk_tokens: int, max_len: int) -> PagePlan:
         geom = self.pages
         plan = PagePlan()
         budget = self.chunk_prefill_tokens
@@ -1411,6 +1426,7 @@ class Scheduler:
                 if budget is None:
                     self.prefix_index.register(req.prompt, req.pages)
             req.status = PREFILLING
+            req.admit_t = spans.now()
             if self.disaggregate:
                 req.owner = PREFILL_ROLE
             self.active[slot] = req
