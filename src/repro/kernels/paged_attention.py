@@ -15,11 +15,15 @@ layout:
     a per-slot contiguous view.
   * :func:`paged_decode_attention` — the public entry. On CPU (and under
     ``impl="ref"``) it gathers and calls the oracle; on TPU it runs the
-    Pallas page-walk kernel: grid over (slot, kv-head, page), the block
-    table scalar-prefetched so the index map DMAs exactly the pages the
-    slot owns — the two-tier pool's analogue of MemPool fetching only the
-    banks a tile maps to. Fully-masked pages (beyond the slot's frontier)
-    are skipped with ``pl.when``.
+    Pallas page walk, :func:`paged_flash_decode`: grid over (slot, block
+    of :func:`walk_pages_per_block` pages), the pools left in HBM and the
+    block table scalar-prefetched, so each cell DMAs exactly the pages of
+    its slot that the frontier (and window) leave visible, all of a page's
+    KV heads in one copy, double-buffered so the next block's pages land
+    while this block's math runs — the two-tier pool's analogue of MemPool
+    fetching only the banks a tile maps to. Blocks wholly outside the
+    visible span start no copy and do no arithmetic, so the walk's cost
+    follows the live pages, not the slot depth.
 
 Page ALIASING is invisible to everything here: attention only ever reads
 through a slot's block table, so two slots mapping the same physical page
@@ -37,18 +41,23 @@ can never land in a page another slot reads.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.hw_profiles import TPU_V5E
 from repro.distributed.sharding import (BATCH, MODEL_AXIS, heads_divide,
                                        kernel_heads_shard, over_heads, shard)
 from repro.kernels import ops
 
 _NEG_INF = float("-inf")
 _STATS_LANES = 128   # stats scratch is (group, 128) for TPU lane alignment
+#: the page walk's double-buffered K and V blocks may take half the scoped
+#: VMEM a kernel gets; the rest is its f32 working set
+_WALK_VMEM_BYTES = TPU_V5E.vmem_limit_bytes // 2
 
 INT8_QMAX = 127.0    # symmetric int8: codes in [-127, 127], -128 unused
 
@@ -152,54 +161,125 @@ def gather_kv_pages(pages: jax.Array, block_tables: jax.Array, *,
 
 # ------------------------------------------------------------ Pallas kernel
 
+#: tokens one grid step of the page walk covers. On a TPU v5e at
+#: qwen2.5-3b's widths (32 slots of 256 pages of 16 tokens), one layer's
+#: walk took 0.278, 0.236, 0.235 and 0.260 ms at 256, 512, 1024 and 2048
+#: tokens a block with chat-like frontiers, 0.387, 0.300, 0.285 and 0.301
+#: ms with code-like ones: fewer, larger steps stop paying once a slot's
+#: last block is mostly masked positions
+WALK_BLOCK_TOKENS = 512
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *,
-                         page_tokens: int, n_pages_per_slot: int,
-                         scale: float, window: int | None):
-    """One (slot, kv-head, logical page) cell of the page walk."""
-    ib, ip = pl.program_id(0), pl.program_id(2)
+
+def walk_pages_per_block(page_tokens: int, p_max: int) -> int:
+    """Pages one grid step of :func:`paged_flash_decode` fetches: about
+    :data:`WALK_BLOCK_TOKENS` tokens, at most a slot's ``p_max`` pages."""
+    return max(1, min(p_max, WALK_BLOCK_TOKENS // page_tokens))
+
+
+def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, m_ref, l_ref, acc_ref, *,
+                         page_tokens: int, ppb: int, p_max: int,
+                         n_blocks: int, scale: float, window: int | None):
+    """One (slot, block of ``ppb`` logical pages) cell of the page walk.
+
+    The slot's visible blocks run from ``first`` (the window's start, else
+    0) to ``last`` (the frontier's block). Each visible block's visible
+    pages are DMA'd from HBM into one half of a double buffer, the next
+    block's pages are started before this block's math, and cells outside
+    ``[first, last]`` start no copy and do no arithmetic."""
+    ib, j = pl.program_id(0), pl.program_id(1)
+    hkv = q_ref.shape[1]
+    bk = ppb * page_tokens
     frontier = len_ref[ib]                    # this slot's filled prefix
+    lo_tok = 0 if window is None else jnp.maximum(frontier - window + 1, 0)
+    first = lo_tok // bk
+    last = jnp.minimum(frontier // bk, n_blocks - 1)
 
-    @pl.when(ip == 0)
+    def copies(blk, buf, i):
+        """Whether page ``i`` of block ``blk`` is visible, and its K and V
+        copies into half ``buf`` of the double buffer."""
+        p = blk * ppb + i
+        vis = (p < p_max) & (p * page_tokens <= frontier)
+        if window is not None:
+            vis &= p * page_tokens + page_tokens > lo_tok
+        page = bt_ref[ib * n_blocks * ppb + p]
+        return vis, (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, i],
+                                           sems.at[0, buf]),
+                     pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, i],
+                                           sems.at[1, buf]))
+
+    def each_page(blk, buf, act):
+        """``act`` on the K and V copies of each visible page of ``blk``.
+        The loop is unrolled when lowered, not in Python: its body is
+        traced once, which takes seconds off tracing a decode chunk."""
+        def body(i, carry):
+            vis, dmas = copies(blk, buf, i)
+
+            @pl.when(vis)
+            def _():
+                for dma in dmas:
+                    act(dma)
+            return carry
+
+        jax.lax.fori_loop(0, ppb, body, 0, unroll=True)
+
+    def start(blk):
+        each_page(blk, blk % 2, lambda dma: dma.start())
+
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # Skip pages wholly beyond the frontier (unmapped tail -> null page).
-    lo = ip * page_tokens
-    visible = lo <= frontier
-    if window is not None:
-        visible &= (lo + page_tokens - 1) > frontier - window
+    @pl.when(j == first)
+    def _prime():
+        start(j)
 
-    @pl.when(visible)
+    @pl.when((j >= first) & (j < last))
+    def _prefetch():
+        start(j + 1)
+
+    @pl.when((j >= first) & (j <= last))
     def _update():
-        q = q_ref[0, 0].astype(jnp.float32)             # (group, d)
-        k = k_ref[0, 0].astype(jnp.float32)             # (pt, d)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        pos = lo + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[0], page_tokens), 1)
-        mask = pos <= frontier                          # causal + written
-        if window is not None:
-            mask &= pos > frontier - window
-        s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.where(m_prev > _NEG_INF, jnp.exp(m_prev - m_new), 0.0)
-        p = jnp.where(s > _NEG_INF, jnp.exp(s - m_new), 0.0)
-        l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        buf = j % 2
+        each_page(j, buf, lambda dma: dma.wait())
 
-    @pl.when(ip == n_pages_per_slot - 1)
+        def visible(shape, axis):
+            pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+            vis = (pos <= frontier) & (pos < p_max * page_tokens)
+            if window is not None:
+                vis &= pos >= lo_tok
+            return vis
+
+        mask, rows = visible((1, bk), 1), visible((bk, 1), 0)
+        for h in range(hkv):
+            q = q_ref[0, h].astype(jnp.float32)                 # (group, d)
+            k = k_buf[buf, :, h].astype(jnp.float32).reshape(bk, -1)
+            v = v_buf[buf, :, h].astype(jnp.float32).reshape(bk, -1)
+            # pages left unfetched hold whatever the buffer held: zero
+            # their rows so masked scores never meet non-finite values
+            v = jnp.where(rows, v, 0.0)
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            s = jnp.where(mask, s, _NEG_INF)
+            m_prev = m_ref[h, :, :1]
+            l_prev = l_ref[h, :, :1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.where(m_prev > _NEG_INF, jnp.exp(m_prev - m_new),
+                              0.0)
+            p = jnp.where(s > _NEG_INF, jnp.exp(s - m_new), 0.0)
+            l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    @pl.when(j == n_blocks - 1)
     def _flush():
-        l = l_ref[:, :1]
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        for h in range(hkv):
+            l = l_ref[h, :, :1]
+            o_ref[0, h] = (acc_ref[h] / jnp.maximum(l, 1e-30)
+                           ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -210,47 +290,61 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Pallas page-walk decode attention.
 
     q: (B, Hq, 1, D); k_pages/v_pages: (n_pages, Hkv, page_tokens, D);
-    block_tables: (B, P) int32; cache_len: (B,) int32. The block table and
-    frontier vector are scalar-prefetched so each grid step's index map
-    resolves the PHYSICAL page to DMA — the kernel never touches pages the
-    slot does not own (page 0 junk is masked like any stale row).
+    block_tables: (B, P) int32; cache_len: (B,) int32. The grid is
+    (slot, block of :func:`walk_pages_per_block` pages); the pools stay in
+    HBM and each cell DMAs, through the scalar-prefetched block table,
+    only the pages of its slot that the frontier (and window) leave
+    visible, all KV heads of a page in one copy. The table is padded with
+    null page 0 to whole blocks; pages past ``P`` are never fetched.
     """
     b, hq, s, d = q.shape
     assert s == 1, "paged decode attention is single-token"
-    n_pages, hkv, page_tokens, dv = (k_pages.shape[0], k_pages.shape[1],
-                                     k_pages.shape[2], v_pages.shape[-1])
+    hkv, page_tokens, dv = (k_pages.shape[1], k_pages.shape[2],
+                            v_pages.shape[-1])
     group = hq // hkv
     p_max = block_tables.shape[1]
+    ppb = walk_pages_per_block(page_tokens, p_max)
+    n_blocks = pl.cdiv(p_max, ppb)
+    page_bytes = lambda x: math.prod(x.shape[1:]) * x.dtype.itemsize
+    buffers = 2 * ppb * (page_bytes(k_pages) + page_bytes(v_pages))
+    if buffers > _WALK_VMEM_BYTES:
+        raise ValueError(
+            f"page walk: {ppb} pages per block need {buffers} bytes of "
+            f"double-buffered VMEM, over {_WALK_VMEM_BYTES}")
     qg = q.reshape(b, hkv, group, d)
+    tables = jnp.pad(block_tables.astype(jnp.int32),
+                     ((0, 0), (0, n_blocks * ppb - p_max))).reshape(-1)
 
     kernel = functools.partial(
-        _paged_decode_kernel, page_tokens=page_tokens,
-        n_pages_per_slot=p_max, scale=d ** -0.5, window=window)
+        _paged_decode_kernel, page_tokens=page_tokens, ppb=ppb, p_max=p_max,
+        n_blocks=n_blocks, scale=d ** -0.5, window=window)
 
+    per_slot = lambda ib, j, bt, ln: (ib, 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, p_max),
+        grid=(b, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, 1, group, d),
-                         lambda ib, ih, ip, bt, ln: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, page_tokens, d),
-                         lambda ib, ih, ip, bt, ln: (bt[ib, ip], ih, 0, 0)),
-            pl.BlockSpec((1, 1, page_tokens, dv),
-                         lambda ib, ih, ip, bt, ln: (bt[ib, ip], ih, 0, 0)),
+            pl.BlockSpec((1, hkv, group, d), per_slot),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, dv),
-                               lambda ib, ih, ip, bt, ln: (ib, ih, 0, 0)),
+        out_specs=pl.BlockSpec((1, hkv, group, dv), per_slot),
         scratch_shapes=[
-            pltpu.VMEM((group, _STATS_LANES), jnp.float32),
-            pltpu.VMEM((group, _STATS_LANES), jnp.float32),
-            pltpu.VMEM((group, dv), jnp.float32),
+            pltpu.VMEM((2, ppb, hkv, page_tokens, d), k_pages.dtype),
+            pltpu.VMEM((2, ppb, hkv, page_tokens, dv), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((hkv, group, _STATS_LANES), jnp.float32),
+            pltpu.VMEM((hkv, group, _STATS_LANES), jnp.float32),
+            pltpu.VMEM((hkv, group, dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, cache_len.astype(jnp.int32), qg, k_pages, v_pages)
+    )(tables, cache_len.astype(jnp.int32), qg, k_pages, v_pages)
     return out.reshape(b, hq, 1, dv)
 
 

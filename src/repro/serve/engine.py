@@ -77,6 +77,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed import sharding as shd
+from repro.kernels.paged_attention import walk_pages_per_block
 from repro.models.api import Model
 from repro.serve import scheduler as sched_mod
 from repro.serve import spans
@@ -86,7 +87,7 @@ from repro.serve.pool import (DECODE_ROLE, PREFILL_ROLE, PoolManager,
 
 __all__ = ["Engine", "EngineConfig", "EngineCore", "PrefillRole",
            "DecodeRole", "PoolState", "ServeReport", "serving_params",
-           "init_serving_params"]
+           "init_serving_params", "walk_counts"]
 
 
 #: Parameter leaves (final path component) that every use casts to the bf16
@@ -124,6 +125,23 @@ def init_serving_params(model: Model, key, mesh=None) -> Any:
     if mesh is not None:
         out = shd.named_shardings(jax.eval_shape(init, key), mesh)
     return jax.jit(init, out_shardings=out)(key)
+
+
+def walk_counts(frontiers: Iterable[int], n: int, n_slots: int,
+                geom: sched_mod.PageGeometry) -> Dict[str, int]:
+    """Page-walk grid cells of one paged decode chunk of ``n`` steps, as
+    ``engine.generate`` args: ``walk_blocks``, the cells (slot, block of
+    :func:`walk_pages_per_block` pages) that hold a visible page at the
+    chunk's last step, summed over the decoding slots whose frontiers
+    were ``frontiers`` at its start; ``walk_grid``, the grid's cells, every
+    slot times the blocks of a block-table row. Their ratio is the share of
+    the grid that fetches anything (for layers without a window)."""
+    p_max = geom.max_pages_per_slot
+    ppb = walk_pages_per_block(geom.page_tokens, p_max)
+    n_blocks = -(-p_max // ppb)
+    bk = ppb * geom.page_tokens
+    blocks = sum(min((f + n - 1) // bk, n_blocks - 1) + 1 for f in frontiers)
+    return {"walk_blocks": blocks, "walk_grid": n_slots * n_blocks}
 
 
 @dataclasses.dataclass
@@ -1229,7 +1247,8 @@ class Engine:
                 else:
                     pool, toks, valid = core._timed(
                         "generate", dec.pool_chunk(n), core.params, pool,
-                        role=dec_role)
+                        role=dec_role, **walk_counts(
+                            frontier.values(), n, sch.n_slots, geom))
                     step_clock += n
                     self.last_stats["decode_steps"] += n
                 t_generate = core.last_phase.start

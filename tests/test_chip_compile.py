@@ -93,11 +93,15 @@ def test_flash_compiles_at_an_unaligned_prompt(one_chip, tpu_routes):
     assert "tpu_custom_call" in text
 
 
-def test_page_walk_compiles_at_the_served_shape(one_chip, tpu_routes):
-    """qwen2.5-3b widths, 4 slots, page_tokens 16, a 4128-token slot depth
-    (258 pages per slot) over a 2065-page pool."""
+@pytest.mark.parametrize("slots,p_max,n_pages", [(32, 256, 5824),
+                                                 (4, 258, 2065)])
+def test_page_walk_compiles_at_the_served_shape(one_chip, tpu_routes, slots,
+                                                p_max, n_pages):
+    """qwen2.5-3b widths, page_tokens 16: the benchmark's 32 slots of 256
+    pages over its 5824-page pool, and 4 slots of a 4128-token depth (258
+    pages, no whole number of walk blocks) over 2065 pages."""
     hq, hkv = WIDTHS["qwen2.5-3b"]
-    slots, p_max, n_pages, pt = 4, 258, 2065, 16
+    pt = 16
     q = _spec(one_chip, (slots, hq, 1, HEAD_DIM))
     pages = _spec(one_chip, (n_pages, hkv, pt, HEAD_DIM))
     tables = _spec(one_chip, (slots, p_max), jnp.int32)
@@ -105,6 +109,7 @@ def test_page_walk_compiles_at_the_served_shape(one_chip, tpu_routes):
     text = _compiled_text(paged_decode_attention, q, pages, pages, tables,
                           lens)
     assert "tpu_custom_call" in text
+    assert "all-gather" not in text
 
 
 def test_matmul3d_compiles_at_a_kernel_bench_shape(one_chip):

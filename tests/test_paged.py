@@ -173,35 +173,51 @@ def test_paged_stream_reuse_and_rejection():
 
 # ------------------------------------------------------ page-walk kernel
 
-def test_paged_flash_decode_matches_oracle():
-    """The Pallas page-walk kernel (interpret mode on CPU) == gather +
-    dense-masked oracle, within online-softmax tolerance."""
+@pytest.mark.parametrize("window", [None, "straddle"])
+@pytest.mark.parametrize("partial_block", [False, True])
+@pytest.mark.parametrize("page_tokens", [8, 16])
+def test_paged_flash_decode_matches_oracle(page_tokens, partial_block,
+                                           window):
+    """The Pallas page-walk kernel (interpret mode on CPU) == the
+    dense-masked oracle over each slot's contiguous view, within
+    online-softmax tolerance. The slots' frontiers sit at 0, either side of
+    and on the first block edge, and on the last position; pages past a
+    frontier map to null page 0 (finite junk); two slots alias one physical
+    page; ``P_max`` is whole blocks, or has a partial block at its end; a
+    window reaches back across a block edge."""
     from repro.kernels.paged_attention import (decode_attention_masked,
-                                               paged_decode_attention)
-    rng = np.random.RandomState(0)
-    b, hq, hkv, d, pt, p_max, n_pages = 3, 4, 2, 16, 8, 4, 13
+                                               paged_decode_attention,
+                                               walk_pages_per_block)
+    rng = np.random.RandomState(page_tokens + 2 * partial_block)
+    hq, hkv, d, pt = 4, 2, 32, page_tokens
+    ppb = walk_pages_per_block(pt, 10**6)
+    p_max = 2 * ppb + (3 if partial_block else 0)
+    assert walk_pages_per_block(pt, p_max) == ppb
+    bk = ppb * pt
+    cache_len = np.asarray([0, bk - 1, bk, bk + 1, p_max * pt - 1], np.int32)
+    # at frontier bk + 1 the window reaches back from block 1 into block 0
+    win = None if window is None else 3 * pt + 5
+    b = len(cache_len)
     bt = np.zeros((b, p_max), np.int32)
-    ids = list(range(1, n_pages))
-    for i in range(b):
-        for p in range(p_max):
-            bt[i, p] = ids.pop()
-    bt = jnp.asarray(bt)
-    cache_len = jnp.asarray([5, 0, 30], jnp.int32)
-    k = jnp.asarray(rng.randn(b, hkv, p_max * pt, d), jnp.float32)
-    v = jnp.asarray(rng.randn(b, hkv, p_max * pt, d), jnp.float32)
-    kp = jnp.zeros((n_pages, hkv, pt, d), jnp.float32)
-    vp = jnp.zeros((n_pages, hkv, pt, d), jnp.float32)
-    for i in range(b):
-        for p in range(p_max):
-            kp = kp.at[bt[i, p]].set(k[i, :, p * pt:(p + 1) * pt])
-            vp = vp.at[bt[i, p]].set(v[i, :, p * pt:(p + 1) * pt])
+    n_pages = 1
+    for i, f in enumerate(cache_len):
+        for p in range(f // pt + 1):
+            bt[i, p], n_pages = n_pages, n_pages + 1
+    bt[2, 0] = bt[1, 0]                        # aliased prefix page
+    kp = rng.randn(n_pages, hkv, pt, d).astype(np.float32)
+    vp = rng.randn(n_pages, hkv, pt, d).astype(np.float32)
+    kp[0] = vp[0] = 1e3                        # null-page junk
+    k = np.concatenate([kp[bt[:, p]] for p in range(p_max)], axis=2)
+    v = np.concatenate([vp[bt[:, p]] for p in range(p_max)], axis=2)
     q = jnp.asarray(rng.randn(b, hq, 1, d), jnp.float32)
-    for window in (None, 6):
-        want = decode_attention_masked(q, k, v, cache_len, window=window)
-        got = paged_decode_attention(q, kp, vp, bt, cache_len,
-                                     window=window, impl="pallas")
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-4, atol=2e-4)
+    cache_len = jnp.asarray(cache_len)
+    want = decode_attention_masked(q, jnp.asarray(k), jnp.asarray(v),
+                                   cache_len, window=win)
+    got = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+                                 jnp.asarray(bt), cache_len, window=win,
+                                 impl="pallas")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
 
 
 # --------------------------------------------- preemption policy edges
