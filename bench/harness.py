@@ -9,7 +9,9 @@ can drive it without a chip.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import importlib.util
 import json
 import math
 import shutil
@@ -21,8 +23,8 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import numpy as np
 
+from repro.distributed import sharding as shd
 from repro.models import build_model
-from repro.models.config import ModelConfig
 from repro.serve import scheduler as sched_mod
 from repro.serve.engine import Engine, EngineConfig
 from repro.serve.pool import pool_bytes_beside
@@ -33,6 +35,7 @@ from bench.gate import Gate
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+FAMILIES_DIR = BENCH_DIR / "families"
 #: seconds of the window a traced run records: a few seconds of steady
 #: serving, short enough that reading the trace stays well inside a run
 TRACE_SECONDS = 5.0
@@ -60,23 +63,22 @@ def metrics_for(spec: Dict, cell: str, trace: bool) -> List[Dict]:
     return [m for m in entries if cell in m.get("workloads", [cell])]
 
 
-def model_config(config: Dict) -> ModelConfig:
-    """The program's config for a configuration file's ``model`` section
-    (Hugging Face key names)."""
-    m = config["model"]
-    if m.get("hidden_act", "silu") != "silu":
-        raise ValueError(f"unsupported activation {m['hidden_act']!r}")
-    return ModelConfig(
-        name=config["name"], family="dense",
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"],
-        head_dim=m.get("head_dim") or config["assumed"]["head_dim"],
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        rope_theta=float(m["rope_theta"]),
-        qkv_bias=bool(config["assumed"]["qkv_bias"]),
-        tie_embeddings=bool(m["tie_word_embeddings"]),
-        norm_eps=float(m["rms_norm_eps"]))
+def load_module(path: Path, prefix: str):
+    """The module in the file ``path``, imported under a name of its own."""
+    name = prefix + path.name[:-3].replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_family(name: str, directory: Path = FAMILIES_DIR):
+    """The model family ``bench/families/<name>.py``: its
+    ``model_config(config)`` and ``token_gaps(...)``."""
+    path = directory / f"{name}.py"
+    if not path.is_file():
+        raise KeyError(f"no model family {name!r} ({path})")
+    return load_module(path, "bench_family_")
 
 
 # --------------------------------------------------------------- compiles
@@ -153,32 +155,73 @@ class Served:
     engine: Engine
     geom: Any
     params: Any
+    family: Any         # the configuration's module under bench/families/
+    mesh: Any = None    # the configuration's mesh; None on one chip
+
+    def scope(self):
+        """The mesh as the ambient one, as the engine's own calls have it,
+        so a program compiled here is the one the window calls."""
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
+
+
+def make_mesh(config: Dict, chips: int):
+    """The mesh the configuration's ``serve.mesh`` names (``{"data": 1,
+    "model": 4}``) over the first ``chips`` devices, or ``None`` where it
+    names none. Refuses a mesh that is not the cell's chips, and heads that
+    do not divide over its ``model`` axis."""
+    axes = config["serve"].get("mesh")
+    if axes is None:
+        if chips != 1:
+            raise ValueError(f"a cell on {chips} chips needs a mesh in its "
+                             f"configuration's serve section")
+        return None
+    size = math.prod(axes.values())
+    if size != chips:
+        raise ValueError(f"mesh {axes} spans {size} devices; the cell asks "
+                         f"for {chips} chips")
+    m = config["model"]
+    model = axes.get(shd.MODEL_AXIS, 1)
+    heads = {"query": m["num_attention_heads"],
+             "KV": m["num_key_value_heads"]}
+    for kind, n in heads.items():
+        if n % model:
+            raise ValueError(f"{n} {kind} heads do not divide over the "
+                             f"{model}-way model axis of mesh {axes}")
+    return shd.make_mesh(tuple(axes.values()), tuple(axes),
+                         devices=jax.devices()[:chips])
 
 
 def build(config: Dict, mix: Dict, seed: int,
-          kv_quant: Optional[str] = None, chips: int = 1) -> Served:
-    """Weights from the seed, the pool beside them, and the engine, as a
-    one-chip deployment builds them. ``kv_quant`` switches on the
-    program's own quantized KV pages (the control of ``bench/control.py``;
-    the cells serve bf16 pages). A cell on more chips is refused: this
-    builds no mesh, so it would serve on one chip against several chips'
-    peaks."""
-    if chips != 1:
-        raise ValueError(f"the harness builds one-chip engines only; a cell "
-                         f"on {chips} chips needs a mesh from its config")
-    cfg = model_config(config)
+          kv_quant: Optional[str] = None, chips: int = 1,
+          families: Path = FAMILIES_DIR) -> Served:
+    """Weights from the seed, the pool beside them, and the engine, as the
+    configuration's deployment builds them: on one chip, or tensor-parallel
+    over its mesh with the weights made straight into their shardings and
+    the pool sized per shard. ``kv_quant`` switches on the program's own
+    quantized KV pages (the control of ``bench/control.py``; the cells
+    serve bf16 pages)."""
+    family = load_family(config["family"], families)
+    mesh = make_mesh(config, chips)
+    cfg = family.model_config(config)
     serve = config["serve"]
     model = build_model(cfg)
-    params = weights.make_params(model.init, seed)
+    place = None
+    if mesh is not None:
+        shapes = jax.eval_shape(model.init, weights.seed_key(seed))
+        place = shd.named_shardings(shapes, mesh)
+    params = weights.make_params(model.init, seed, out_shardings=place)
     layer0, layer1 = pool_bytes_beside(params)
     geom = sched_mod.derive_page_geometry(
         cfg, serve["max_len"], layer0_bytes=layer0, layer1_bytes=layer1,
+        model_shards=1 if mesh is None else mesh.shape[shd.MODEL_AXIS],
         kv_quant=kv_quant)
     engine = Engine(model, params, EngineConfig(
         max_len=serve["max_len"],
         prompt_pad_multiple=serve["prompt_pad_multiple"],
-        eos_token=-1 if mix.get("ignore_eos") else 1))
-    return Served(engine=engine, geom=geom, params=params)
+        eos_token=-1 if mix.get("ignore_eos") else 1, mesh=mesh))
+    return Served(engine=engine, geom=geom, params=params, family=family,
+                  mesh=mesh)
 
 
 def warm(served: Served, config: Dict, mix: Dict) -> None:
@@ -199,11 +242,12 @@ def warm(served: Served, config: Dict, mix: Dict) -> None:
     req = sched_mod.Request(rid=0, prompt=np.full((4,), 2, np.int32),
                             max_new_tokens=2, tokens=[2], pages=[0],
                             status=sched_mod.DECODING)
-    spill = eng.pools.exec_spill(pool, spill, sched_mod.SpillAction(
-        slot=0, req=req, src_pages=[0], dst_pages=[0], seat=0), p_max)
-    pool = eng.pools.exec_restore(pool, spill, sched_mod.RestoreAction(
-        slot=0, req=req, src_pages=[0], seat=0), p_max)
-    jax.block_until_ready((pool, spill))
+    with served.scope():
+        spill = eng.pools.exec_spill(pool, spill, sched_mod.SpillAction(
+            slot=0, req=req, src_pages=[0], dst_pages=[0], seat=0), p_max)
+        pool = eng.pools.exec_restore(pool, spill, sched_mod.RestoreAction(
+            slot=0, req=req, src_pages=[0], seat=0), p_max)
+        jax.block_until_ready((pool, spill))
     eng._last_pool = eng._last_spill = None
     del pool, spill
 
@@ -260,14 +304,15 @@ def run(*, spec: Dict, cell: Dict, config: Dict, mix: Dict, seed: int,
         compiles: CompileLog, control: bool = False,
         kv_quant: Optional[str] = None,
         readers: Optional[Dict[str, Callable]] = None,
-        keep_trace: Optional[Path] = None,
+        keep_trace: Optional[Path] = None, families: Path = FAMILIES_DIR,
         log: Callable[[str], None] = print) -> Result:
     """One measured run; ``device`` is what :func:`run.device_check`
     found. ``control`` judges the fp8 reference's picks in the program's
     place (the program's own gaps go to the notes); ``kv_quant`` serves
     with the program's quantized KV pages. A run with a compile inside the
     window is not correct."""
-    served = build(config, mix, seed, kv_quant, chips=cell["chips"])
+    served = build(config, mix, seed, kv_quant, chips=cell["chips"],
+                   families=families)
     warm(served, config, mix)
     shape, serve = config["model"], config["serve"]
     tracer = Tracer(min(TRACE_SECONDS, seconds)) if trace else None
@@ -294,16 +339,19 @@ def run(*, spec: Dict, cell: Dict, config: Dict, mix: Dict, seed: int,
     # reference in the program's place, and its picks are judged instead
     limit = config["check"]["logit_gap_limit"]
     picks = sample(gate, seed, mix.get("check_tokens", 256))
-    served_g, control_g = reference.token_gaps(
+    t_check = time.perf_counter()
+    served_g, control_g = served.family.token_gaps(
         shape, served.params, picks, n_out=mix["output"]["max"],
         control=control)
+    check_s = time.perf_counter() - t_check
     judged = reference.summary(control_g if control else served_g)
     checks = {"logit_gap": {"value": judged["widest"], "limit": limit},
               "compiles_in_window": {"value": n_compiles, "limit": 0}}
     correct = (bool(picks) and judged["widest"] <= limit
                and n_compiles == 0)
     notes = [f"checked {len(picks)} requests, {len(served_g)} served "
-             f"tokens: program {reference.summary(served_g)}"]
+             f"tokens in {check_s:.1f} s: program "
+             f"{reference.summary(served_g)}"]
     gaps = {"program": reference.summary(served_g)}
     if control:
         gaps["control"] = judged
@@ -332,6 +380,10 @@ def run(*, spec: Dict, cell: Dict, config: Dict, mix: Dict, seed: int,
     notes.append(f"window: {r.n_in_window()} requests due, "
                  f"{len(gate.boundaries)} boundaries in the run, gate slept "
                  f"{gate.wait_s:.3f} s, compile seconds {compiles.seconds:.3f}")
+    notes.append(f"pool: {gate.page_pool.high_water} of "
+                 f"{served.geom.n_pages - 1} pages at most, "
+                 f"{gate.preemptions} spills, {gate.restores} restores, "
+                 f"{sum(x.rejected for x in gate.records.values())} rejected")
     return Result(correct=correct, attempted=attempted, failed=failed,
                   metrics=metrics, device=dev, breakdown=breakdown,
                   checks=checks, notes=notes, gaps=gaps)

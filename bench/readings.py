@@ -80,6 +80,8 @@ class Readings:
         if name == "itl_p95_ms":
             xs = self.itls_s()
             return 1e3 * percentile(xs, 95) if xs else None
+        if name == "output_tok_s":
+            return self.output_tokens() / self.seconds
         if name == "setup_s":
             return self.setup_s
         raise KeyError(f"no end-to-end reading {name!r}")
@@ -112,6 +114,14 @@ class Readings:
         tokens = sum(g for b in bs for _, g in b.decode)
         return 100.0 * tokens / (len(bs) * self.sync * self.n_slots)
 
+    def pages_peak_pct(self) -> Optional[float]:
+        """Most layer-0 pages in use at once over the run, of the pool's
+        pages (the null page left out), in %."""
+        pool = self.gate.page_pool
+        if pool is None or pool.n_pages <= 1:
+            return None
+        return 100.0 * pool.high_water / (pool.n_pages - 1)
+
     # ---------------------------------------------------------------- trace
     def busy_s(self) -> Optional[float]:
         return trace_mod.busy_s(self.trace) if self.trace else None
@@ -123,6 +133,15 @@ class Readings:
         if not self.trace:
             return None
         return 100.0 * (1.0 - self.busy_s() / self.window_s())
+
+    def collective_exposed_pct(self) -> Optional[float]:
+        """Share of the traced window in which a collective ran with no
+        other op on its chip, averaged over chips, in %."""
+        if not self.trace:
+            return None
+        exposed = trace_mod.collective_exposed_s(self.trace)
+        return None if exposed is None else \
+            100.0 * exposed / self.window_s()
 
     def breakdown(self) -> Optional[Dict]:
         return trace_mod.breakdown(self.trace) if self.trace else None

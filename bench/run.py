@@ -6,7 +6,10 @@
 Run from the root of a checkout; the cell, its configuration, its traffic
 mix and its per-layer metric readers are found by name from
 ``BENCHMARK.json`` (``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json``, ``bench/metrics/<metric>.py``).
+``bench/traffic/<traffic>.json``, ``bench/metrics/<metric>.py``), and the
+configuration's model family by its ``family``
+(``bench/families/<family>.py``). A configuration whose ``serve`` names a
+``mesh`` is served tensor-parallel over that many chips.
 
 The run needs a TPU whose ``device_kind`` has a published peak in
 ``bench/peaks.py`` and as many chips as the cell asks for; anything else
@@ -29,7 +32,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -60,16 +62,13 @@ def device_check(chips: int) -> dict:
 
 def load_readers(names, directory: Path) -> dict:
     """``read`` of each per-layer metric's own reader file."""
+    from bench.harness import load_module
     out = {}
     for name in names:
         path = directory / f"{name}.py"
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-        if spec is None or not path.is_file():
+        if not path.is_file():
             raise KeyError(f"no reader for metric {name!r} ({path})")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        out[name] = mod.read
+        out[name] = load_module(path, "bench_metric_").read
     return out
 
 
@@ -112,6 +111,7 @@ def main(argv=None, *, root: Path = ROOT, check_device=device_check,
                          trace=bool(args.trace), device=device,
                          t_start=T_START, compiles=compiles, readers=readers,
                          keep_trace=args.keep_trace,
+                         families=root / "bench" / "families",
                          log=lambda s: print(s, flush=True))
     for note in result.notes:
         print(note, flush=True)

@@ -46,7 +46,7 @@ def main(argv=None) -> int:
     config = json.loads((ROOT / entry["file"]).read_text())
     mix = traffic.load_mix(cell["traffic"])
     device = device_check(cell["chips"])
-    served = harness.build(config, mix, args.seed)
+    served = harness.build(config, mix, args.seed, chips=cell["chips"])
     harness.warm(served, config, mix)
     serve = config["serve"]
     for rate in (float(x) for x in args.rates.split(",")):

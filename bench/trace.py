@@ -218,15 +218,23 @@ def program_s(tr: Trace, program: str) -> float:
     return _mean(per_dev)
 
 
+def is_collective(op: Op) -> bool:
+    """A collective by the op's own name (an async pair's ``-start`` and
+    ``-done`` too), not by the operands a v5e trace lists after it."""
+    return bool(COLLECTIVE.search(base_name(op.name)))
+
+
 def collective_exposed_s(tr: Trace) -> Optional[float]:
     """Seconds in which a collective ran with no other op on that chip,
-    averaged over chips; ``None`` where the trace holds no collective."""
+    averaged over chips; ``None`` where the trace holds no collective. A
+    loop or other container that holds the collective is no other op."""
     found, per_dev = False, []
     for dev in tr.ops:
-        coll = union(((o.start, o.end) for o in dev
-                      if COLLECTIVE.search(o.name)), *tr.window)
+        coll = union(((o.start, o.end) for o in dev if is_collective(o)),
+                     *tr.window)
         comp = union(((o.start, o.end) for o in dev
-                      if not COLLECTIVE.search(o.name)), *tr.window)
+                      if not is_collective(o)
+                      and base_name(o.name) not in CONTAINERS), *tr.window)
         found = found or bool(coll)
         per_dev.append(subtract(coll, comp))
     return _mean(per_dev) if found else None

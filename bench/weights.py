@@ -59,7 +59,9 @@ def _maker(init_fn, seed: int):
     return make, key
 
 
-def make_params(init_fn, seed: int) -> Any:
-    """The weights for ``seed`` in the tree ``init_fn(key)`` would build."""
+def make_params(init_fn, seed: int, out_shardings: Any = None) -> Any:
+    """The weights for ``seed`` in the tree ``init_fn(key)`` would build;
+    with ``out_shardings`` each leaf is made in its shards, so a model
+    larger than one device never passes through one."""
     make, key = _maker(init_fn, seed)
-    return jax.jit(make)(key)
+    return jax.jit(make, out_shardings=out_shardings)(key)

@@ -2,8 +2,9 @@
 without a TPU or without the program; a sound run comes out correct, and
 a run whose served tokens are altered where they are produced, whose
 window holds a compile, or whose tokens are the fp8 control's, does not;
-and a configuration, a mix and a metric added as new files are found by
-name.
+a configuration, a mix, a metric and a model family added as new files
+are found by name; the four-chip cell runs on a mesh of four CPU devices,
+and a mesh that does not fit the cell or its heads is refused.
 """
 
 from __future__ import annotations
@@ -24,6 +25,8 @@ import bench.run as run_mod
 from bench import harness, traffic
 from bench_helpers import REPO, cpu_device
 from repro.serve import engine as engine_mod
+from repro.serve import scheduler as sched_mod
+from repro.serve.pool import pool_bytes_beside
 
 ARGS = ["--seed", "3000000019", "--seconds", "2"]
 
@@ -170,9 +173,116 @@ def test_a_compile_inside_the_window_is_not_correct(tiny_root, monkeypatch):
     assert res.correct is False
 
 
-def test_a_cell_on_more_chips_is_refused(tiny_root):
+def _with_config(root, name, **serve):
+    """The configuration ``name`` of ``root`` with its ``serve`` updated
+    (a key set to ``None`` taken out)."""
+    path = root / "bench" / "configs" / f"{name}.json"
+    cfg = json.loads(path.read_text())
+    cfg["serve"].update(serve)
+    cfg["serve"] = {k: v for k, v in cfg["serve"].items() if v is not None}
+    path.write_text(json.dumps(cfg))
+
+
+def test_a_cell_whose_heads_do_not_divide_is_refused(tiny_root):
+    """The tiny qwen2.5-3b has 2 KV heads: a 4-way model axis cannot
+    split them, and the refusal comes before any device is touched."""
+    _with_config(tiny_root, "qwen2.5-3b", mesh={"data": 1, "model": 4})
     spec = json.loads((tiny_root / "BENCHMARK.json").read_text())
     spec["workloads"][0]["chips"] = 4
     (tiny_root / "BENCHMARK.json").write_text(json.dumps(spec))
-    with pytest.raises(ValueError, match="one-chip"):
+    with pytest.raises(ValueError, match=r"2 KV heads do not divide over "
+                                         r"the 4-way model axis"):
         run_cell(tiny_root, spec["workloads"][0]["name"])
+
+
+@pytest.mark.parametrize("mesh, chips, match", [
+    (None, 4, "needs a mesh"),
+    ({"data": 1, "model": 2}, 4, "spans 2 devices"),
+], ids=["no_mesh_on_four_chips", "mesh_not_the_cells_chips"])
+def test_a_mesh_that_is_not_the_cells_chips_is_refused(tiny_root, mesh,
+                                                       chips, match):
+    _with_config(tiny_root, "mistral-nemo-12b-tp4", mesh=mesh)
+    with pytest.raises(ValueError, match=match):
+        harness.make_mesh(json.loads(
+            (tiny_root / "bench/configs/mistral-nemo-12b-tp4.json")
+            .read_text()), chips)
+
+
+def test_a_configuration_without_a_mesh_builds_as_on_one_chip(tiny_root):
+    """No mesh: no mesh object, the weights on one device, and the page
+    geometry the pool's byte budget beside them gives unsharded."""
+    config = json.loads(
+        (tiny_root / "bench/configs/qwen2.5-3b.json").read_text())
+    assert "mesh" not in config["serve"]
+    mix = traffic.load_mix("chat", tiny_root / "bench" / "traffic")
+    served = harness.build(config, mix, 3000000019,
+                           families=tiny_root / "bench" / "families")
+    assert served.mesh is None and served.engine.ecfg.mesh is None
+    assert all(len(leaf.sharding.device_set) == 1
+               for leaf in jax.tree.leaves(served.params))
+    layer0, layer1 = pool_bytes_beside(served.params)
+    cfg = served.family.model_config(config)
+    assert served.geom == sched_mod.derive_page_geometry(
+        cfg, config["serve"]["max_len"], layer0_bytes=layer0,
+        layer1_bytes=layer1)
+
+
+@pytest.mark.parametrize("fault", [None, "spill", "no_exchange",
+                                   "fp8_control"],
+                         ids=["sound", "spilling", "exchange_left_out",
+                              "fp8_control"])
+def test_the_four_chip_cell_on_a_mesh(fault, tmp_path):
+    """The tiny mistral-nemo-12b-tp4.chat-batch through ``main`` on four
+    CPU devices, its weights and KV pages split four ways and its
+    end-to-end metrics reported: correct, also where a pool of eight
+    pages makes sequences spill and come back; not correct where each
+    chip keeps its share of the down projection to itself or where the
+    fp8 reference's picks stand in the program's place."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "tests/bench/mesh_run_check.py"),
+         str(tmp_path / "checkout"), *([fault] if fault else [])],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    lines = proc.stdout.strip().splitlines()
+    res, lay = json.loads(lines[-2]), json.loads(lines[-1])
+    sound = fault in (None, "spill")
+    assert res["correct"] is sound and res["attempted"] > 0
+    gap = res["checks"]["logit_gap"]
+    assert (gap["value"] <= gap["limit"]) is sound
+    assert res["checks"]["compiles_in_window"]["value"] == 0
+    assert (lay["spills"] > 0 and lay["restores"] > 0) is (fault == "spill")
+    assert set(res["metrics"]) == {"output_tok_s", "setup_s"}
+    assert res["metrics"]["output_tok_s"]["value"] > 0
+    assert res["device"]["count"] == 4
+    for tree in ("weights", "pool"):
+        assert lay[tree] == {"devices": 4, "largest_share": 0.25}, lay
+
+
+def test_a_family_file_is_found_by_name(tiny_root, capsys):
+    """A configuration names a family added as a new file; the run
+    checks its outputs through that file."""
+    fam = tiny_root / "bench" / "families"
+    (fam / "dense_marked.py").write_text(
+        (fam / "dense.py").read_text()
+        + "\n\ndef token_gaps(*a, **k):\n"
+          "    from pathlib import Path\n"
+          "    Path(__file__).with_suffix('.used').touch()\n"
+          "    return reference.token_gaps(*a, **k)\n")
+    path = tiny_root / "bench/configs/qwen2.5-3b.json"
+    cfg = json.loads(path.read_text())
+    cfg["family"] = "dense_marked"
+    path.write_text(json.dumps(cfg))
+    res = drive(tiny_root, "qwen2.5-3b.chat", capsys)
+    assert res["correct"] is True
+    assert (fam / "dense_marked.used").is_file()
+
+
+def test_an_unknown_family_is_refused(tiny_root):
+    path = tiny_root / "bench/configs/qwen2.5-3b.json"
+    cfg = json.loads(path.read_text())
+    cfg["family"] = "no-such-family"
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(KeyError, match=r"families/no-such-family\.py"):
+        run_cell(tiny_root, "qwen2.5-3b.chat")

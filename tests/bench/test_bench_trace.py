@@ -1,7 +1,8 @@
 """Trace reduction: busy and idle time as a union of op intervals,
 kernel and program time by stable name, collective exposure, and idle
-gaps labelled by the gate's host spans -- on hand-built traces and on a
-small trace recorded on a TPU v5e (``bench/testdata/``)."""
+gaps labelled by the gate's host spans -- on hand-built traces and on
+small traces recorded on one TPU v5e and on a four-chip v5e host
+(``bench/testdata/``)."""
 
 from __future__ import annotations
 
@@ -59,6 +60,25 @@ def test_no_collective_reads_nothing():
     assert tr.collective_exposed_s(t) is None
 
 
+def test_async_collective_pair_is_found_by_its_own_name():
+    """An async all-reduce shows as a ``-start`` and a ``-done`` op; a
+    v5e trace names each op by its whole instruction, so a fusion that
+    reads the collective's result names it too, and a loop holds them."""
+    dev = [Op("%while.1 = (s32[]) while(%tuple.2), body=%body", 0.0, 10.0),
+           Op("%all-reduce-start.3 = bf16[48,5120] all-reduce-start("
+              "%fusion.1)", 1.0, 1.5),
+           Op("%fusion.4 = bf16[48,5120] fusion(%x)", 1.5, 3.0),
+           Op("%all-reduce-done.3 = bf16[48,5120] all-reduce-done("
+              "%all-reduce-start.3)", 3.0, 4.0),
+           Op("%fusion.5 = bf16[48,5120] fusion(%all-reduce-done.3)",
+              4.0, 6.0)]
+    t = Trace(ops=[dev], modules=[[]], host=[], window=(0.0, 10.0))
+    assert [tr.is_collective(o) for o in dev] == [False, True, False, True,
+                                                  False]
+    # start 1-1.5 and done 3-4 run alone; the loop around them is no op
+    assert tr.collective_exposed_s(t) == pytest.approx(1.5)
+
+
 def test_breakdown_labels_gaps_by_host_span():
     b = tr.breakdown(hand_built())
     gaps = dict((k, v) for k, v in b["idle_gaps"])
@@ -94,3 +114,40 @@ def test_recorded_v5e_trace():
     assert "while" not in dict(b["device_ops"])
     assert {k for k, _ in b["idle_gaps"]} <= {"engine", "gate.plan",
                                               "gate.wait"}
+
+
+def test_recorded_four_chip_v5e_trace():
+    """32 ms of a traced run of mistral-nemo-12b-tp4.chat-batch on a
+    four-chip v5e host (seed 7): a decode chunk's end, a gate.plan gap,
+    and an admission. There the collectives are synchronous ops named
+    ``%all-reduce.N`` and ``%all-gather.N``, with no async pair; fusions
+    that read them name them among their operands, and the loops that
+    hold them are ops of their own."""
+    t = tr.load_json(TESTDATA / "v5e_tp4_admit_decode.json.gz")
+    assert len(t.ops) == 4
+    for dev in t.ops:
+        coll = [o for o in dev if tr.is_collective(o)]
+        assert {tr.base_name(o.name) for o in coll} == {"all-reduce",
+                                                        "all-gather"}
+        assert all(o.name.startswith(("%all-reduce.", "%all-gather."))
+                   for o in coll)
+        # operands name collectives too; those ops are not collectives
+        assert any(tr.COLLECTIVE.search(o.name) and not tr.is_collective(o)
+                   for o in dev)
+    assert any(tr.base_name(o.name) == "while" for o in t.ops[0])
+    # a synchronous collective runs alone on its chip: all of it is
+    # exposed, the loops around it notwithstanding
+    coll_s = sum(sum(o.end - o.start for o in dev if tr.is_collective(o))
+                 for dev in t.ops) / 4
+    assert 0 < tr.collective_exposed_s(t) == pytest.approx(coll_s, rel=1e-3)
+    # busy and idle per chip, averaged: not the union over the chips
+    per_chip = [tr.length(tr.union(((o.start, o.end) for o in dev),
+                                   *t.window)) for dev in t.ops]
+    every = tr.length(tr.union(((o.start, o.end) for dev in t.ops
+                                for o in dev), *t.window))
+    assert len(set(per_chip)) == 4
+    assert tr.busy_s(t) == pytest.approx(sum(per_chip) / 4, rel=1e-12)
+    assert tr.busy_s(t) < every < tr.window_s(t)
+    # the gap is the scheduler's plan, on every chip
+    assert tr.breakdown(t)["idle_gaps"][0][0] == "gate.plan"
+    assert 0 < tr.kernel_s(t, "page_walk") and 0 < tr.kernel_s(t, "flash")
