@@ -4,7 +4,24 @@ reference (``bench/reference.py``).
 
 A family file gives ``model_config(config)`` and ``token_gaps(shape,
 params, samples, *, n_out, control)``; the harness finds it by the
-configuration's ``family`` (``bench/families/<family>.py``).
+configuration's ``family`` (``bench/families/<family>.py``). It may also
+give, and the harness then takes:
+
+* ``weight_rule(path, shape, key)``: a leaf's value in its served type,
+  or ``None`` to leave it to the dense rules of ``bench/weights.py``;
+* ``decode_token_flops(shape, attended)`` and ``prefill_flops(shape,
+  length)``: the model flops of one decoded token and of one prefilled
+  prompt, for the step MFUs (else ``bench/counts.py``'s);
+* ``KERNEL_WORK``: kernel name -> ``fn(shape, boundary)``, the (flops,
+  bytes) one traced boundary needs in that kernel at its true lengths;
+* ``KERNELS`` (kernel name -> the jitted function that calls it, or its
+  name) and ``PROGRAMS`` (``"decode"`` / ``"admit"`` -> the kernel that
+  marks that program), laid over ``bench/trace.py``'s own tables;
+* ``sharded_heads(config)``: what has to divide over a mesh's ``model``
+  axis, by name (else the query and KV heads).
+
+The dense family gives none of these: its weights, counts and kernel
+names are the harness's own.
 """
 
 from __future__ import annotations

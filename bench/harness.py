@@ -73,8 +73,8 @@ def load_module(path: Path, prefix: str):
 
 
 def load_family(name: str, directory: Path = FAMILIES_DIR):
-    """The model family ``bench/families/<name>.py``: its
-    ``model_config(config)`` and ``token_gaps(...)``."""
+    """The model family ``bench/families/<name>.py``: what a family file
+    gives, and what it may give, is set out in ``bench/families/dense.py``."""
     path = directory / f"{name}.py"
     if not path.is_file():
         raise KeyError(f"no model family {name!r} ({path})")
@@ -165,11 +165,22 @@ class Served:
                 else contextlib.nullcontext())
 
 
-def make_mesh(config: Dict, chips: int):
+def sharded_heads(config: Dict, family=None) -> Dict[str, int]:
+    """The counts split over the ``model`` axis of a mesh, by what they
+    count: the family's ``sharded_heads(config)`` where it gives one, else
+    the query and KV heads of the model section."""
+    if hasattr(family, "sharded_heads"):
+        return family.sharded_heads(config)
+    m = config["model"]
+    return {"query heads": m["num_attention_heads"],
+            "KV heads": m["num_key_value_heads"]}
+
+
+def make_mesh(config: Dict, chips: int, family=None):
     """The mesh the configuration's ``serve.mesh`` names (``{"data": 1,
     "model": 4}``) over the first ``chips`` devices, or ``None`` where it
     names none. Refuses a mesh that is not the cell's chips, and heads that
-    do not divide over its ``model`` axis."""
+    do not divide over its ``model`` axis (:func:`sharded_heads`)."""
     axes = config["serve"].get("mesh")
     if axes is None:
         if chips != 1:
@@ -180,13 +191,10 @@ def make_mesh(config: Dict, chips: int):
     if size != chips:
         raise ValueError(f"mesh {axes} spans {size} devices; the cell asks "
                          f"for {chips} chips")
-    m = config["model"]
     model = axes.get(shd.MODEL_AXIS, 1)
-    heads = {"query": m["num_attention_heads"],
-             "KV": m["num_key_value_heads"]}
-    for kind, n in heads.items():
+    for kind, n in sharded_heads(config, family).items():
         if n % model:
-            raise ValueError(f"{n} {kind} heads do not divide over the "
+            raise ValueError(f"{n} {kind} do not divide over the "
                              f"{model}-way model axis of mesh {axes}")
     return shd.make_mesh(tuple(axes.values()), tuple(axes),
                          devices=jax.devices()[:chips])
@@ -202,7 +210,7 @@ def build(config: Dict, mix: Dict, seed: int,
     quantized KV pages (the control of ``bench/control.py``; the cells
     serve bf16 pages)."""
     family = load_family(config["family"], families)
-    mesh = make_mesh(config, chips)
+    mesh = make_mesh(config, chips, family)
     cfg = family.model_config(config)
     serve = config["serve"]
     model = build_model(cfg)
@@ -210,7 +218,8 @@ def build(config: Dict, mix: Dict, seed: int,
     if mesh is not None:
         shapes = jax.eval_shape(model.init, weights.seed_key(seed))
         place = shd.named_shardings(shapes, mesh)
-    params = weights.make_params(model.init, seed, out_shardings=place)
+    params = weights.make_params(model.init, seed, out_shardings=place,
+                                 family=family)
     layer0, layer1 = pool_bytes_beside(params)
     geom = sched_mod.derive_page_geometry(
         cfg, serve["max_len"], layer0_bytes=layer0, layer1_bytes=layer1,
@@ -362,7 +371,8 @@ def run(*, spec: Dict, cell: Dict, config: Dict, mix: Dict, seed: int,
                           n_slots=serve["n_slots"],
                           sync_interval=served.engine.ecfg.sync_interval,
                           chips=cell["chips"], kind=device["kind"],
-                          trace=tr, tracer=tracer, setup_s=setup_s)
+                          trace=tr, tracer=tracer, setup_s=setup_s,
+                          family=served.family)
     metrics = {}
     for m in metrics_for(spec, cell["name"], trace):
         value = (readers[m["name"]](r) if trace

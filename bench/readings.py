@@ -2,6 +2,12 @@
 scheduler's counters and the device trace -- to numbers. The per-layer
 readers under ``bench/metrics/`` each pick one of these; a reading with
 nothing to read returns ``None``.
+
+Work is counted, and kernels and programs named, by the configuration's
+model family where it gives them (``decode_token_flops``,
+``prefill_flops``, ``KERNEL_WORK``, ``KERNELS``, ``PROGRAMS``: see
+``bench/families/dense.py``), and by ``bench/counts.py`` (dense GQA with
+SwiGLU, :data:`WORK`) and ``bench/trace.py``'s tables otherwise.
 """
 
 from __future__ import annotations
@@ -14,6 +20,23 @@ from bench import counts, peaks
 from bench import trace as trace_mod
 
 
+def _total(work) -> Tuple[float, float]:
+    flops = nbytes = 0.0
+    for f, n in work:
+        flops, nbytes = flops + f, nbytes + n
+    return flops, nbytes
+
+
+#: kernel -> ``fn(shape, boundary)``, the (flops, bytes) one traced
+#: boundary needed in that kernel of ``bench/trace.py``'s own table
+WORK = {
+    "page_walk": lambda shape, b: _total(
+        counts.page_walk(shape, a) for a in counts.decode_steps(b.decode)),
+    "flash": lambda shape, b: _total(
+        counts.flash(shape, length) for length in b.admitted),
+}
+
+
 def percentile(xs, q: float) -> float:
     """Linear-interpolated percentile; 0 for an empty sample."""
     return float(np.percentile(np.asarray(xs, np.float64), q)) if len(xs) \
@@ -24,12 +47,18 @@ class Readings:
     def __init__(self, *, gate, seconds: float, shape: Dict, n_slots: int,
                  sync_interval: int, chips: int, kind: str,
                  trace: Optional["trace_mod.Trace"] = None, tracer=None,
-                 setup_s: float = 0.0):
+                 setup_s: float = 0.0, family=None):
         self.gate, self.seconds, self.shape = gate, seconds, shape
         self.n_slots, self.sync = n_slots, sync_interval
         self.chips, self.trace, self.tracer = chips, trace, tracer
         self.peak = peaks.peak_for(kind)
         self.setup_s = setup_s
+        self.kernels, self.programs = trace_mod.tables(family)
+        self.work = {**WORK, **getattr(family, "KERNEL_WORK", {})}
+        self.decode_token_flops = getattr(family, "decode_token_flops",
+                                          counts.decode_token_flops)
+        self.prefill_flops = getattr(family, "prefill_flops",
+                                     counts.prefill_flops)
 
     # ---------------------------------------------------------- the window
     def _in(self, t: float) -> bool:
@@ -144,28 +173,21 @@ class Readings:
             100.0 * exposed / self.window_s()
 
     def breakdown(self) -> Optional[Dict]:
-        return trace_mod.breakdown(self.trace) if self.trace else None
+        return (trace_mod.breakdown(self.trace, kernels=self.kernels)
+                if self.trace else None)
 
     def _work(self, kernel: str) -> Tuple[float, float]:
         """(flops, bytes) the traced boundaries needed in ``kernel``."""
-        flops = nbytes = 0.0
-        for b in self.traced_boundaries():
-            if kernel == "page_walk":
-                for a in counts.decode_steps(b.decode):
-                    f, n = counts.page_walk(self.shape, a)
-                    flops, nbytes = flops + f, nbytes + n
-            elif kernel == "flash":
-                for length in b.admitted:
-                    f, n = counts.flash(self.shape, length)
-                    flops, nbytes = flops + f, nbytes + n
-        return flops, nbytes
+        work = self.work.get(kernel)
+        return _total(work(self.shape, b) for b in self.traced_boundaries()) \
+            if work else (0.0, 0.0)
 
     def roofline_pct(self, kernel: str) -> Optional[float]:
         """Least time the needed work could take on the chips over the
         kernel's device time (averaged over chips), in %."""
         if not self.trace:
             return None
-        seconds = trace_mod.kernel_s(self.trace, kernel)
+        seconds = trace_mod.kernel_s(self.trace, kernel, self.kernels)
         flops, nbytes = self._work(kernel)
         if seconds <= 0 or flops <= 0:
             return None
@@ -180,13 +202,14 @@ class Readings:
         time, chips and peak, in %."""
         if not self.trace:
             return None
-        seconds = trace_mod.program_s(self.trace, program)
+        seconds = trace_mod.program_s(self.trace, program, self.kernels,
+                                      self.programs)
         bs = self.traced_boundaries()
         if program == "decode":
-            flops = sum(counts.decode_token_flops(self.shape, a)
+            flops = sum(self.decode_token_flops(self.shape, a)
                         for b in bs for a in counts.decode_steps(b.decode))
         else:
-            flops = sum(counts.prefill_flops(self.shape, length)
+            flops = sum(self.prefill_flops(self.shape, length)
                         for b in bs for length in b.admitted)
         if seconds <= 0 or flops <= 0:
             return None

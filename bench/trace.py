@@ -6,6 +6,11 @@ trace can also be stored as JSON (``bench/testdata/``). Times are seconds
 on the trace's own clock. The window is the host span
 :data:`WINDOW_SPAN`, which the harness opens right after the profiler
 starts and closes right before it stops.
+
+Kernels and programs are found by name through :data:`KERNELS` and
+:data:`PROGRAMS`; a model family may add entries of its own (its
+``KERNELS`` and ``PROGRAMS``), joined to these by :func:`tables`, and the
+reductions that find kernels take the joined tables.
 """
 
 from __future__ import annotations
@@ -191,26 +196,41 @@ def base_name(name: str) -> str:
     return re.sub(r"[.\d]+$", "", name)
 
 
-def is_kernel(op: Op, kernel: str) -> bool:
-    name = KERNELS[kernel]
+def tables(family=None) -> Tuple[Dict[str, str], Dict[str, str]]:
+    """The kernel and program tables: :data:`KERNELS` and :data:`PROGRAMS`
+    with a model family's own ``KERNELS`` (name -> the jitted function
+    that calls the kernel, or its name) and ``PROGRAMS`` (program -> the
+    kernel that marks it) laid over them."""
+    kernels = {**KERNELS, **{k: getattr(fn, "__name__", fn) for k, fn in
+                             getattr(family, "KERNELS", {}).items()}}
+    return kernels, {**PROGRAMS, **getattr(family, "PROGRAMS", {})}
+
+
+def is_kernel(op: Op, kernel: str, kernels: Dict[str, str] = KERNELS
+              ) -> bool:
+    name = kernels[kernel]
     return (base_name(op.name) == name
             or f"jit({name})/pallas_call" in op.detail)
 
 
-def kernel_s(tr: Trace, kernel: str) -> float:
+def kernel_s(tr: Trace, kernel: str, kernels: Dict[str, str] = KERNELS
+             ) -> float:
     """Device seconds of ``kernel``'s calls, averaged over chips."""
-    return _mean(sum(o.end - o.start for o in dev if is_kernel(o, kernel))
+    return _mean(sum(o.end - o.start for o in dev
+                     if is_kernel(o, kernel, kernels))
                  for dev in tr.ops)
 
 
-def program_s(tr: Trace, program: str) -> float:
+def program_s(tr: Trace, program: str, kernels: Dict[str, str] = KERNELS,
+              programs: Dict[str, str] = PROGRAMS) -> float:
     """Device seconds of the module runs that hold a call of
     ``program``'s marking kernel, averaged over chips. Runs are matched
     by time: a v5e trace gives its ops no program id."""
-    kernel = PROGRAMS[program]
+    kernel = programs[program]
     per_dev = []
     for dev_ops, dev_mods in zip(tr.ops, tr.modules):
-        marks = sorted(o.start for o in dev_ops if is_kernel(o, kernel))
+        marks = sorted(o.start for o in dev_ops
+                       if is_kernel(o, kernel, kernels))
         per_dev.append(sum(
             m.end - m.start for m in dev_mods
             if bisect.bisect_left(marks, m.start) < len(marks)
@@ -240,11 +260,11 @@ def collective_exposed_s(tr: Trace) -> Optional[float]:
     return _mean(per_dev) if found else None
 
 
-def op_label(op: Op) -> str:
+def op_label(op: Op, kernels: Dict[str, str] = KERNELS) -> str:
     """A name that survives renumbering: the kernel's name for the
     Pallas kernels, else the op name without its instance number."""
-    for kernel, name in KERNELS.items():
-        if is_kernel(op, kernel):
+    for kernel, name in kernels.items():
+        if is_kernel(op, kernel, kernels):
             return name
     return base_name(op.name)
 
@@ -271,7 +291,8 @@ def idle_gaps(tr: Trace) -> List[Tuple[str, float]]:
     return out
 
 
-def breakdown(tr: Trace, top: int = 10) -> Dict[str, List]:
+def breakdown(tr: Trace, top: int = 10, kernels: Dict[str, str] = KERNELS
+              ) -> Dict[str, List]:
     """The ops that took most device time (averaged over chips; loops and
     other containers left out) and the longest idle gaps, each at most
     ``top`` entries."""
@@ -280,7 +301,7 @@ def breakdown(tr: Trace, top: int = 10) -> Dict[str, List]:
         for o in dev:
             if (o.end > tr.window[0] and o.start < tr.window[1]
                     and base_name(o.name) not in CONTAINERS):
-                key = op_label(o)
+                key = op_label(o, kernels)
                 tot[key] = tot.get(key, 0.0) + (o.end - o.start) / len(tr.ops)
     ops = sorted(tot.items(), key=lambda kv: -kv[1])[:top]
     gaps = sorted(idle_gaps(tr), key=lambda g: -g[1])[:top]
