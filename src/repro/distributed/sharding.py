@@ -165,20 +165,26 @@ def over_heads(fn, arg_axes: Sequence[str]):
     automatically partitioned"). Attention is independent per slot and per
     head, so a shard_map that hands each shard its own slice computes
     exactly the unsharded result. ``arg_axes`` names, per argument, which
-    of its leading axes split: ``"b"`` splits axis 0 (the slot batch) over
-    :data:`BATCH`, ``"h"`` splits axis 1 (heads) over `model`, ``"bh"``
-    both. The output is ``"bh"``. An axis that does not
-    divide its mesh axes stays whole (:func:`fix_spec_for`). With GQA the
-    query and KV head slices line up whenever :func:`kernel_heads_shard`
-    holds. Identity without a multi-device mesh."""
+    of its axes split: ``"b"`` splits axis 0 (the slot batch) over
+    :data:`BATCH`, ``"h"`` splits the head axis, third from the right as
+    :func:`spec_for_cache` places it (a query's ``(B, H, S, D)``, a page
+    pool's ``(n_pages, H, pt, D)`` or a stacked pool's ``(L, n_pages, H,
+    pt, D)``), over `model`, ``"bh"`` both, ``""`` neither. The output is
+    ``"bh"``. An axis that does not divide its mesh axes stays whole
+    (:func:`fix_spec_for`). With GQA the query and KV head slices line up
+    whenever :func:`kernel_heads_shard` holds. Identity without a
+    multi-device mesh."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.size <= 1:
         return fn
 
     def spec(axes: str, shape) -> P:
-        base = (BATCH if "b" in axes else None,
-                MODEL_AXIS if "h" in axes else None)
-        return fix_spec_for(mesh, P(*base[:len(shape)]), shape)
+        base = [None] * len(shape)
+        if "b" in axes:
+            base[0] = BATCH
+        if "h" in axes:
+            base[-3] = MODEL_AXIS
+        return fix_spec_for(mesh, P(*base), shape)
 
     def run(*args):
         in_specs = tuple(spec(a, x.shape) for a, x in zip(arg_axes, args))

@@ -143,16 +143,18 @@ def decode_attention_masked(q, k, v, cache_len, *, window=None, causal=True):
 
 
 def gather_kv_pages(pages: jax.Array, block_tables: jax.Array, *,
-                    seq_axis: int) -> jax.Array:
+                    seq_axis: int, layer=None) -> jax.Array:
     """Walk the block table: physical pages -> per-slot contiguous KV.
 
     ``pages`` is ``(n_pages, *page_shape)`` with ``page_shape[seq_axis] ==
-    page_tokens``; ``block_tables`` is ``(B, P)`` int32. Returns
+    page_tokens``, or the stacked pool ``(L, n_pages, *page_shape)`` read
+    at index ``layer``; ``block_tables`` is ``(B, P)`` int32. Returns
     ``(B, *page_shape)`` with the seq axis widened to ``P * page_tokens``.
     Unmapped entries (null page 0) gather junk that the caller's frontier
     mask must hide — exactly like stale rows in the dense slab.
     """
-    gathered = pages[block_tables]                 # (B, P, *page_shape)
+    lead = () if layer is None else (layer,)
+    gathered = pages[lead + (block_tables,)]       # (B, P, *page_shape)
     gathered = jnp.moveaxis(gathered, 1, seq_axis + 1)
     shape = list(gathered.shape)
     merged = shape[:seq_axis + 1] + [shape[seq_axis + 1] * shape[seq_axis + 2]]
@@ -176,11 +178,12 @@ def walk_pages_per_block(page_tokens: int, p_max: int) -> int:
     return max(1, min(p_max, WALK_BLOCK_TOKENS // page_tokens))
 
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sems, m_ref, l_ref, acc_ref, *,
+def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref, *,
                          page_tokens: int, ppb: int, p_max: int,
                          n_blocks: int, scale: float, window: int | None):
-    """One (slot, block of ``ppb`` logical pages) cell of the page walk.
+    """One (slot, block of ``ppb`` logical pages) cell of the page walk
+    over layer ``layer_ref[0]`` of the stacked pool.
 
     The slot's visible blocks run from ``first`` (the window's start, else
     0) to ``last`` (the frontier's block). Each visible block's visible
@@ -188,6 +191,7 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     block's pages are started before this block's math, and cells outside
     ``[first, last]`` start no copy and do no arithmetic."""
     ib, j = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
     hkv = q_ref.shape[1]
     bk = ppb * page_tokens
     frontier = len_ref[ib]                    # this slot's filled prefix
@@ -203,10 +207,10 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         if window is not None:
             vis &= p * page_tokens + page_tokens > lo_tok
         page = bt_ref[ib * n_blocks * ppb + p]
-        return vis, (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, i],
-                                           sems.at[0, buf]),
-                     pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, i],
-                                           sems.at[1, buf]))
+        return vis, (pltpu.make_async_copy(k_hbm.at[layer, page],
+                                           k_buf.at[buf, i], sems.at[0, buf]),
+                     pltpu.make_async_copy(v_hbm.at[layer, page],
+                                           v_buf.at[buf, i], sems.at[1, buf]))
 
     def each_page(blk, buf, act):
         """``act`` on the K and V copies of each visible page of ``blk``.
@@ -284,28 +288,31 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                       block_tables: jax.Array, cache_len: jax.Array, *,
+                       block_tables: jax.Array, cache_len: jax.Array,
+                       layer: jax.Array | int, *,
                        window: int | None = None,
                        interpret: bool = False) -> jax.Array:
     """Pallas page-walk decode attention.
 
-    q: (B, Hq, 1, D); k_pages/v_pages: (n_pages, Hkv, page_tokens, D);
-    block_tables: (B, P) int32; cache_len: (B,) int32. The grid is
-    (slot, block of :func:`walk_pages_per_block` pages); the pools stay in
-    HBM and each cell DMAs, through the scalar-prefetched block table,
-    only the pages of its slot that the frontier (and window) leave
-    visible, all KV heads of a page in one copy. The table is padded with
-    null page 0 to whole blocks; pages past ``P`` are never fetched.
+    q: (B, Hq, 1, D); k_pages/v_pages: the stacked pool (L, n_pages, Hkv,
+    page_tokens, D), read at index ``layer``; block_tables: (B, P) int32;
+    cache_len: (B,) int32. The grid is (slot, block of
+    :func:`walk_pages_per_block` pages); the pools stay in HBM, where the
+    decode chunk's layer scan keeps them, and each cell DMAs, through the
+    scalar-prefetched block table and layer index, only the pages of its
+    slot that the frontier (and window) leave visible, all KV heads of a
+    page in one copy. The table is padded with null page 0 to whole
+    blocks; pages past ``P`` are never fetched.
     """
     b, hq, s, d = q.shape
     assert s == 1, "paged decode attention is single-token"
-    hkv, page_tokens, dv = (k_pages.shape[1], k_pages.shape[2],
+    hkv, page_tokens, dv = (k_pages.shape[2], k_pages.shape[3],
                             v_pages.shape[-1])
     group = hq // hkv
     p_max = block_tables.shape[1]
     ppb = walk_pages_per_block(page_tokens, p_max)
     n_blocks = pl.cdiv(p_max, ppb)
-    page_bytes = lambda x: math.prod(x.shape[1:]) * x.dtype.itemsize
+    page_bytes = lambda x: math.prod(x.shape[2:]) * x.dtype.itemsize
     buffers = 2 * ppb * (page_bytes(k_pages) + page_bytes(v_pages))
     if buffers > _WALK_VMEM_BYTES:
         raise ValueError(
@@ -319,9 +326,9 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         _paged_decode_kernel, page_tokens=page_tokens, ppb=ppb, p_max=p_max,
         n_blocks=n_blocks, scale=d ** -0.5, window=window)
 
-    per_slot = lambda ib, j, bt, ln: (ib, 0, 0, 0)
+    per_slot = lambda ib, j, *prefetched: (ib, 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_blocks),
         in_specs=[
             pl.BlockSpec((1, hkv, group, d), per_slot),
@@ -344,7 +351,8 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(tables, cache_len.astype(jnp.int32), qg, k_pages, v_pages)
+    )(tables, cache_len.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pages, v_pages)
     return out.reshape(b, hq, 1, dv)
 
 
@@ -354,12 +362,20 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, block_tables: jax.Array,
                            cache_len: jax.Array, *,
+                           layer: jax.Array | int | None = None,
                            window: int | None = None,
                            causal: bool = True,
                            impl: str = "auto",
                            k_scale: jax.Array | None = None,
                            v_scale: jax.Array | None = None) -> jax.Array:
     """Decode attention over the paged pool; dense math is the oracle.
+
+    The pools are one layer's ``(n_pages, Hkv, page_tokens, D)``, or, with
+    ``layer``, the stacked ``(L, n_pages, Hkv, page_tokens, D)`` the decode
+    chunk's layer scan carries, read at index ``layer`` where they lie:
+    the walk DMAs that layer's pages and the gather indexes them, so no
+    layer is sliced out. Int8 scales follow their pages: ``(n_pages,)`` or
+    ``(L, n_pages)``.
 
     ``impl="auto"`` walks pages with the Pallas kernel on TPU and takes the
     gather + :func:`decode_attention_masked` path elsewhere — the latter is
@@ -382,6 +398,11 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     half the bytes, the math is unchanged. The Pallas kernel stays
     fp16-only for now, so quantized pools always take the gather path.
     """
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    hkv = k_pages.shape[-3]
     on_tpu = ops.on_tpu()
     single = q.shape[2] == 1
     quantized = (k_scale is not None
@@ -393,25 +414,26 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
             "dequantize on gather (impl='auto')")
     use_pallas = (impl == "pallas") or (
         impl == "auto" and on_tpu and single and not quantized
-        and kernel_heads_shard(k_pages.shape[1]))
+        and kernel_heads_shard(hkv))
     if use_pallas and causal:
         walk = functools.partial(paged_flash_decode, window=window,
                                  interpret=not on_tpu)
-        return over_heads(walk, ("bh", "h", "h", "b", "b"))(
-            q, k_pages, v_pages, block_tables, cache_len)
-    k = gather_kv_pages(k_pages, block_tables, seq_axis=1)
-    v = gather_kv_pages(v_pages, block_tables, seq_axis=1)
+        return over_heads(walk, ("bh", "h", "h", "b", "b", ""))(
+            q, k_pages, v_pages, block_tables, cache_len,
+            jnp.asarray(layer, jnp.int32))
+    k = gather_kv_pages(k_pages, block_tables, seq_axis=1, layer=layer)
+    v = gather_kv_pages(v_pages, block_tables, seq_axis=1, layer=layer)
     if k_scale is not None:
         # per-page scalar scales -> per-token columns of the gathered view
-        pt = k_pages.shape[2]
-        ks = jnp.repeat(k_scale[block_tables], pt, axis=1)    # (B, P*pt)
-        vs = jnp.repeat(v_scale[block_tables], pt, axis=1)
+        pt = k_pages.shape[3]
+        ks = jnp.repeat(k_scale[layer, block_tables], pt, axis=1)  # (B, P*pt)
+        vs = jnp.repeat(v_scale[layer, block_tables], pt, axis=1)
         k = k.astype(jnp.float32) * ks[:, None, :, None]
         v = v.astype(jnp.float32) * vs[:, None, :, None]
     elif quantized:
         k = k.astype(jnp.float32)
         v = v.astype(jnp.float32)
-    if heads_divide(k_pages.shape[1]):
+    if heads_divide(hkv):
         # pin the gathered per-slot view to the head shards that own the
         # pages: the block-table gather indexes the (replicated-looking)
         # page axis, and without the constraint GSPMD may resolve it by

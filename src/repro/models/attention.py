@@ -86,11 +86,15 @@ def _cache_write(cache_arr: jax.Array, new: jax.Array, cache_len,
 
 def _paged_cache_write(pages: jax.Array, new: jax.Array,
                        cache_len: jax.Array, block_tables: jax.Array,
-                       axis: int) -> jax.Array:
+                       axis: int, *, layer=None) -> jax.Array:
     """Block-table-aware token append into the paged pool.
 
     ``pages`` is ``(n_pages, *page_shape)`` with the token axis at ``axis``
-    inside a page (GQA: 1, MLA: 0); ``new`` is the dense write
+    inside a page (GQA: 1, MLA: 0), or, with ``layer``, the stacked pool
+    ``(L, n_pages, *page_shape)`` the decode chunk's layer scan carries:
+    the rows land at ``[layer, page, ..., offset]`` and nothing else of the
+    pool is touched, so a donated pool is updated in place. ``new`` is the
+    dense write
     ``(B, ..., s, ...)`` — ``s == 1`` for ordinary decode, ``s == k+1`` for
     a speculative verify chunk. Each row's token ``j`` resolves
     ``cache_len + j -> (physical page, in-page offset)`` through its
@@ -105,7 +109,8 @@ def _paged_cache_write(pages: jax.Array, new: jax.Array,
     own frontier page at admission (DESIGN.md §Prefix sharing &
     copy-on-write) — so no guard is needed here.
     """
-    pt = pages.shape[1 + axis]
+    lead = () if layer is None else (layer,)
+    pt = pages.shape[len(lead) + 1 + axis]
     p_max = block_tables.shape[1]
     s = new.shape[1 + axis]
     new = new.astype(pages.dtype)
@@ -117,20 +122,30 @@ def _paged_cache_write(pages: jax.Array, new: jax.Array,
         phys = jnp.where(pos < p_max * pt, phys, 0)
         off = pos % pt
         if axis == 0:
-            pages = pages.at[phys, off].set(new[:, j])
+            pages = pages.at[lead + (phys, off)].set(new[:, j])
         else:
-            pages = pages.at[phys, :, off].set(new[:, :, j])
+            # the row's whole page, its token set at ``off``: a write whose
+            # window is a page keeps the pool in the layout the walk reads
+            at = lead + (phys,)
+            page = pages[at]                               # (B, H, pt, D)
+            iota = jax.lax.broadcasted_iota(jnp.int32, page.shape, 2)
+            page = jnp.where(iota == off[:, None, None, None],
+                             new[:, :, j, None], page)
+            pages = pages.at[at].set(page)
     return pages
 
 
 def _paged_cache_write_q(pages: jax.Array, scales: jax.Array, new: jax.Array,
                          cache_len: jax.Array, block_tables: jax.Array,
-                         axis: int) -> Tuple[jax.Array, jax.Array]:
+                         axis: int, *, layer=None
+                         ) -> Tuple[jax.Array, jax.Array]:
     """Int8 block-table token append with a monotone per-page scale.
 
     Same ``cache_len + j -> (page, offset)`` resolution as
-    :func:`_paged_cache_write`, but the pool holds int8 codes plus one f32
-    amax-scale per page (DESIGN.md §Tiered KV compression & host parking).
+    :func:`_paged_cache_write`, and the same ``layer`` index into a stacked
+    pool (scales ``(L, n_pages)``), but the pool holds int8 codes plus one
+    f32 amax-scale per page (DESIGN.md §Tiered KV compression & host
+    parking).
     Per appended token: the page's scale grows to cover the new value
     (``max(old, amax(new)/127)`` — monotone, so history codes only ever
     get COARSER, never clip), the already-resident codes are requantized at
@@ -145,7 +160,8 @@ def _paged_cache_write_q(pages: jax.Array, scales: jax.Array, new: jax.Array,
     reason :func:`_paged_cache_write` needs no guard: writes resolve only
     to pages private to the row by scheduler invariant.
     """
-    pt = pages.shape[1 + axis]
+    lead = () if layer is None else (layer,)
+    pt = pages.shape[len(lead) + 1 + axis]
     p_max = block_tables.shape[1]
     s = new.shape[1 + axis]
     newf = new.astype(jnp.float32)
@@ -159,11 +175,12 @@ def _paged_cache_write_q(pages: jax.Array, scales: jax.Array, new: jax.Array,
         tok = newf[:, j] if axis == 0 else newf[:, :, j]
         fresh = jnp.max(jnp.abs(tok),
                         axis=tuple(range(1, tok.ndim))) / INT8_QMAX
-        old = scales[phys]
+        at = lead + (phys,)
+        old = scales[at]
         scl = jnp.where(off == 0, fresh, jnp.maximum(old, fresh))
         safe = jnp.where(scl > 0, scl, 1.0)
-        page_shape_ones = (1,) * (pages.ndim - 1)
-        page_f = (pages[phys].astype(jnp.float32)
+        page_shape_ones = (1,) * (pages.ndim - 1 - len(lead))
+        page_f = (pages[at].astype(jnp.float32)
                   * old.reshape((-1,) + page_shape_ones))
         safe_b = safe.reshape((-1,) + page_shape_ones)
         page_new = jnp.clip(jnp.round(page_f / safe_b), -INT8_QMAX,
@@ -175,8 +192,8 @@ def _paged_cache_write_q(pages: jax.Array, scales: jax.Array, new: jax.Array,
         off_b = off.reshape((-1,) + page_shape_ones)
         page_new = jnp.where(iota == off_b,
                              jnp.expand_dims(tok_codes, 1 + axis), page_new)
-        pages = pages.at[phys].set(page_new.astype(pages.dtype))
-        scales = scales.at[phys].set(scl)
+        pages = pages.at[at].set(page_new.astype(pages.dtype))
+        scales = scales.at[at].set(scl)
     return pages, scales
 
 
@@ -231,6 +248,7 @@ def gqa_attention(p: Dict, x: jax.Array, *, cfg: ModelConfig,
                   causal: bool = True,
                   plan: Optional[tiling.AttentionPlan] = None,
                   block_tables: Optional[jax.Array] = None,
+                  layer: Optional[jax.Array] = None,
                   ) -> Tuple[jax.Array, Optional[Dict]]:
     """x: (B, S, d). Returns (out, updated_cache).
 
@@ -239,7 +257,9 @@ def gqa_attention(p: Dict, x: jax.Array, *, cfg: ModelConfig,
     cross-attention (cross_kv given: precomputed encoder K/V, no cache write).
     With ``block_tables`` the cache is the paged page pool instead of a
     per-slot slab: write + attention both walk the table (S == 1 for
-    ordinary decode; S == k+1 for a speculative verify chunk).
+    ordinary decode; S == k+1 for a speculative verify chunk). The pool's
+    leaves are then stacked over the layer group's repetitions and read
+    and written at index ``layer`` where they lie.
     """
     b, s, _ = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -268,18 +288,19 @@ def gqa_attention(p: Dict, x: jax.Array, *, cfg: ModelConfig,
         if "k_scale" in cache:
             k_pages, k_scales = _paged_cache_write_q(
                 cache["k"], cache["k_scale"], k, cache_len, block_tables,
-                axis=1)
+                axis=1, layer=layer)
             v_pages, v_scales = _paged_cache_write_q(
                 cache["v"], cache["v_scale"], v, cache_len, block_tables,
-                axis=1)
+                axis=1, layer=layer)
             new_cache = {"k": k_pages, "v": v_pages,
                          "k_scale": k_scales, "v_scale": v_scales}
         else:
             k_pages = _paged_cache_write(cache["k"], k, cache_len,
-                                         block_tables, axis=1)
+                                         block_tables, axis=1, layer=layer)
             v_pages = _paged_cache_write(cache["v"], v, cache_len,
-                                         block_tables, axis=1)
+                                         block_tables, axis=1, layer=layer)
             new_cache = {"k": k_pages, "v": v_pages}
+        lead = (None,) * (k_pages.ndim - 4)     # the stacked pool's layer axis
         if heads_divide(hkv):
             # head-axis page placement: each mesh shard holds the page slice
             # its own KV heads read (q heads follow by GQA grouping), so the
@@ -289,17 +310,18 @@ def gqa_attention(p: Dict, x: jax.Array, *, cfg: ModelConfig,
             # size; the geometry prices against the scaled aggregate
             # (DESIGN.md §Sharded serving).
             q = shard(q, BATCH, MODEL_AXIS, None, None)
-            k_pages = shard(k_pages, None, MODEL_AXIS, None, None)
-            v_pages = shard(v_pages, None, MODEL_AXIS, None, None)
+            k_pages = shard(k_pages, *lead, None, MODEL_AXIS, None, None)
+            v_pages = shard(v_pages, *lead, None, MODEL_AXIS, None, None)
         else:
             # heads don't divide: the page axis takes the seq shards' role
             # (pages spread over `model`); q replicates exactly as in the
             # dense pooled-decode layout.
             q = shard(q, BATCH, None, None, None)
-            k_pages = shard(k_pages, MODEL_AXIS, None, None, None)
-            v_pages = shard(v_pages, MODEL_AXIS, None, None, None)
+            k_pages = shard(k_pages, *lead, MODEL_AXIS, None, None, None)
+            v_pages = shard(v_pages, *lead, MODEL_AXIS, None, None, None)
         out = paged_decode_attention(q, k_pages, v_pages, block_tables,
-                                     cache_len, window=kind.window,
+                                     cache_len, layer=layer,
+                                     window=kind.window,
                                      causal=causal, k_scale=k_scales,
                                      v_scale=v_scales)
         out = out.transpose(0, 2, 1, 3).reshape(b, s, hq * hd)
@@ -417,6 +439,7 @@ def mla_attention(p: Dict, x: jax.Array, *, cfg: ModelConfig,
                   cache_len: Optional[jax.Array] = None,
                   plan: Optional[tiling.AttentionPlan] = None,
                   block_tables: Optional[jax.Array] = None,
+                  layer: Optional[jax.Array] = None,
                   **_unused) -> Tuple[jax.Array, Optional[Dict]]:
     """Multi-head latent attention. Cache stores only the 576-dim latent —
     the paper's 'more capacity in the same footprint', algorithmically."""
@@ -442,37 +465,40 @@ def mla_attention(p: Dict, x: jax.Array, *, cfg: ModelConfig,
     if cache is not None and block_tables is not None:
         # paged latent pool: block-table write, then gather back the same
         # contiguous per-slot view the dense slab holds — the absorbed
-        # decode below is untouched and bit-exact with the dense path. An
+        # decode below is untouched and bit-exact with the dense path. A
+        # stacked pool is written and gathered at index ``layer``. An
         # int8 latent pool dequantizes on the gather (codes × per-page
         # scale); fp8 upcasts in the gather's astype. Beyond-frontier
         # positions hold junk either way — masked exactly like stale K/V.
         if "ckv_scale" in cache:
             ckv_pages, ckv_scales = _paged_cache_write_q(
                 cache["ckv"], cache["ckv_scale"], ckv, cache_len,
-                block_tables, axis=0)
+                block_tables, axis=0, layer=layer)
             krope_pages, krope_scales = _paged_cache_write_q(
                 cache["krope"], cache["krope_scale"], k_rope, cache_len,
-                block_tables, axis=0)
+                block_tables, axis=0, layer=layer)
             new_cache = {"ckv": ckv_pages, "krope": krope_pages,
                          "ckv_scale": ckv_scales,
                          "krope_scale": krope_scales}
-            pt = ckv_pages.shape[1]
-            ckv = (gather_kv_pages(ckv_pages, block_tables, seq_axis=0)
-                   .astype(jnp.float32)
-                   * jnp.repeat(ckv_scales[block_tables], pt,
-                                axis=1)[:, :, None])
-            k_rope = (gather_kv_pages(krope_pages, block_tables, seq_axis=0)
-                      .astype(jnp.float32)
-                      * jnp.repeat(krope_scales[block_tables], pt,
-                                   axis=1)[:, :, None])
+            at = (block_tables,) if layer is None else (layer, block_tables)
+            pt = ckv_pages.shape[-2]
+            ckv = (gather_kv_pages(ckv_pages, block_tables, seq_axis=0,
+                                   layer=layer).astype(jnp.float32)
+                   * jnp.repeat(ckv_scales[at], pt, axis=1)[:, :, None])
+            k_rope = (gather_kv_pages(krope_pages, block_tables, seq_axis=0,
+                                      layer=layer).astype(jnp.float32)
+                      * jnp.repeat(krope_scales[at], pt, axis=1)[:, :, None])
         else:
             ckv_pages = _paged_cache_write(cache["ckv"], ckv, cache_len,
-                                           block_tables, axis=0)
+                                           block_tables, axis=0, layer=layer)
             krope_pages = _paged_cache_write(cache["krope"], k_rope,
-                                             cache_len, block_tables, axis=0)
+                                             cache_len, block_tables, axis=0,
+                                             layer=layer)
             new_cache = {"ckv": ckv_pages, "krope": krope_pages}
-            ckv = gather_kv_pages(ckv_pages, block_tables, seq_axis=0)
-            k_rope = gather_kv_pages(krope_pages, block_tables, seq_axis=0)
+            ckv = gather_kv_pages(ckv_pages, block_tables, seq_axis=0,
+                                  layer=layer)
+            k_rope = gather_kv_pages(krope_pages, block_tables, seq_axis=0,
+                                     layer=layer)
             if ckv.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
                 ckv = ckv.astype(jnp.float32)       # fp8 tier: decode=upcast
                 k_rope = k_rope.astype(jnp.float32)

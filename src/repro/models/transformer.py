@@ -76,7 +76,8 @@ def init_lm(cfg: ModelConfig, key) -> Params:
 
 def _apply_layer(cfg: ModelConfig, kind: LayerKind, p: Params, x: jax.Array,
                  *, positions, positions3, cache, cache_len,
-                 plans: Optional[KernelPlans] = None, block_tables=None):
+                 plans: Optional[KernelPlans] = None, block_tables=None,
+                 layer=None):
     """Returns (x, aux, new_cache)."""
     aux = jnp.zeros((), jnp.float32)
     h = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
@@ -91,7 +92,7 @@ def _apply_layer(cfg: ModelConfig, kind: LayerKind, p: Params, x: jax.Array,
             p["attn"], h, cfg=cfg, kind=kind, positions=positions,
             positions3=positions3, cache=cache, cache_len=cache_len,
             plan=plans.attention if plans else None,
-            block_tables=block_tables)
+            block_tables=block_tables, layer=layer)
     x = x + y
     if kind.mlp == "mlp":
         x = x + layers.mlp(p["mlp"], layers.rmsnorm(p["ln2"], x, cfg.norm_eps))
@@ -106,20 +107,53 @@ def _apply_layer(cfg: ModelConfig, kind: LayerKind, p: Params, x: jax.Array,
 def _superblock(cfg: ModelConfig, group: LayerGroup, stacked: Params,
                 x: jax.Array, caches, cache_len, positions, positions3,
                 aux: jax.Array, plans: Optional[KernelPlans] = None,
-                block_tables=None):
-    """Apply one repetition of ``group.pattern``. stacked/caches are the
-    per-repetition slices (no leading axis here)."""
+                block_tables=None, layer=None):
+    """Apply repetition ``layer`` of ``group.pattern``. ``stacked`` and the
+    scanned caches are that repetition's slices (no leading axis here);
+    the paged pool's leaves are whole, read and written at ``layer``."""
     new_caches = {}
     for pos, kind in enumerate(group.pattern):
         cache_i = caches.get(f"pos{pos}") if caches else None
         x, aux_i, nc = _apply_layer(cfg, kind, stacked[f"pos{pos}"], x,
                                     positions=positions, positions3=positions3,
                                     cache=cache_i, cache_len=cache_len,
-                                    plans=plans, block_tables=block_tables)
+                                    plans=plans, block_tables=block_tables,
+                                    layer=layer)
         aux = aux + aux_i
         if nc is not None:
             new_caches[f"pos{pos}"] = nc
     return x, aux, new_caches
+
+
+def _split_pool(group: LayerGroup, g_caches, paged_pool: bool):
+    """``(pool, scanned)``: a group's paged attention caches, which the
+    layer scan carries whole, and the rest, which it slices per repetition
+    (``xs``) and restacks (``ys``).
+
+    In a paged pool every attention position's leaves are the
+    stacked page pool ``(n_repeat, n_pages, ...)``: carried, each step
+    writes only its new rows into it and the walk reads repetition ``l``
+    where it lies, so a donated pool is updated in place instead of being
+    sliced out and rebuilt beside itself. Recurrent state and the dense
+    slot slab are per slot and stay scanned."""
+    if g_caches is None or not paged_pool:
+        return {}, g_caches
+    paged = {f"pos{i}" for i, kind in enumerate(group.pattern)
+             if kind.attn != "mamba"}
+    return ({pos: c for pos, c in g_caches.items() if pos in paged},
+            {pos: c for pos, c in g_caches.items() if pos not in paged})
+
+
+def pool_leaves(cfg: ModelConfig, caches) -> Dict[str, int]:
+    """Of the paged pool's leaves (every leaf of an attention position, as
+    :func:`paged_cache_kinds` walks them), how many the decode forward's
+    layer scan carries in place (:func:`_split_pool`)."""
+    paged = sum(len(jax.tree.leaves(caches[g][pos]))
+                for g, pos, is_paged in paged_cache_kinds(cfg) if is_paged)
+    in_place = sum(
+        len(jax.tree.leaves(_split_pool(group, caches[group.name], True)[0]))
+        for group in cfg.layer_groups())
+    return {"pool_leaves_in_place": in_place, "pool_leaves_paged": paged}
 
 
 def _run_groups(cfg: ModelConfig, params: Params, x: jax.Array, *,
@@ -130,24 +164,29 @@ def _run_groups(cfg: ModelConfig, params: Params, x: jax.Array, *,
     new_caches: Dict[str, Any] = {}
     for group in cfg.layer_groups():
         stacked = params["groups"][group.name]
-        g_caches = caches.get(group.name) if caches else None
+        pool, g_caches = _split_pool(
+            group, caches.get(group.name) if caches else None,
+            block_tables is not None)
 
         def body(carry, xs, _group=group):
-            xc, auxc = carry
+            xc, auxc, layer, pool_c = carry
             p_slice, c_slice = xs
-            xo, auxo, nc = _superblock(cfg, _group, p_slice, xc, c_slice,
+            xo, auxo, nc = _superblock(cfg, _group, p_slice, xc,
+                                       {**(c_slice or {}), **pool_c},
                                        cache_len, positions, positions3, auxc,
-                                       plans, block_tables)
-            return (xo, auxo), nc
+                                       plans, block_tables, layer)
+            pool_c = {pos: nc.pop(pos) for pos in pool_c}
+            return (xo, auxo, layer + 1, pool_c), nc
 
         if remat:
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.nothing_saveable)
-        (x, aux), nc_stacked = jax.lax.scan(
-            body, (x, aux), (stacked, g_caches),
+        (x, aux, _, pool), nc_stacked = jax.lax.scan(
+            body, (x, aux, jnp.zeros((), jnp.int32), pool),
+            (stacked, g_caches),
             unroll=runtime_flags.scan_unroll(group.n_repeat))
         if caches is not None:
-            new_caches[group.name] = nc_stacked
+            new_caches[group.name] = {**nc_stacked, **pool}
     return x, aux, new_caches
 
 

@@ -78,6 +78,7 @@ import numpy as np
 
 from repro.distributed import sharding as shd
 from repro.kernels.paged_attention import walk_pages_per_block
+from repro.models import transformer
 from repro.models.api import Model
 from repro.serve import scheduler as sched_mod
 from repro.serve import spans
@@ -1144,6 +1145,10 @@ class Engine:
             self.last_stats.update(speculate_tokens=spec_k,
                                    spec_proposed=0, spec_accepted=0)
         pool, spill = pools.init_paged_pool(sch)
+        # how many of the pool's paged leaves the decode forward's layer
+        # scan carries in place; on every engine.generate span
+        leaves = transformer.pool_leaves(core.model.cfg, pool.state["caches"])
+        self.last_stats.update(leaves)
         pending_first: List[Tuple[sched_mod.Request, jax.Array]] = []
         boundary_wall: List[float] = []
         boundary_tokens: List[int] = []
@@ -1240,14 +1245,14 @@ class Engine:
                     pool, toks, valid = core._timed(
                         "generate", dec.verify_fn(spec_k), core.params, pool,
                         jnp.asarray(drafts), jnp.asarray(dlen),
-                        role=dec_role)
+                        role=dec_role, **leaves)
                     step_clock += 1
                     self.last_stats["decode_steps"] += 1
                     self.last_stats["spec_proposed"] += int(dlen.sum())
                 else:
                     pool, toks, valid = core._timed(
                         "generate", dec.pool_chunk(n), core.params, pool,
-                        role=dec_role, **walk_counts(
+                        role=dec_role, **leaves, **walk_counts(
                             frontier.values(), n, sch.n_slots, geom))
                     step_clock += n
                     self.last_stats["decode_steps"] += n

@@ -45,12 +45,16 @@ DECODE_ROLE = sched_mod.DECODE_ROLE
 
 # ----------------------------------------------------- device pool budget
 
-#: How many copies of the layer-0 pool the costliest jitted step holds at
-#: once. The steps donate the pool, so argument and output share buffers;
-#: the decode chunk's scan over the stacked layers still builds the updated
-#: caches beside the old ones (``memory_analysis()`` of the pooled decode
-#: chunk at qwen2.5-3b widths on a v5e: temp = pool + 0.57 GB).
-#: tests/test_chip_compile.py holds the compiled steps to this count.
+#: How many copies of the layer-0 pool the pool budget charges. The steps
+#: donate the pool, so argument and output share buffers, and the decode
+#: chunk's layer scan carries the stacked pool and writes its new rows in
+#: place: its compiled scratch holds no second pool (``memory_analysis()``
+#: of the pooled decode chunk at qwen2.5-3b widths on a v5e: temp 0.30 GB
+#: beside a 3.44 GB pool). The charge stays 2 so that the pool's page
+#: count, and every benchmark cell's, is what it was when the decode chunk
+#: still rebuilt the pool beside itself; lowering it, and so serving a
+#: larger pool, is a change of its own. tests/test_chip_compile.py holds
+#: the compiled steps to this budget.
 POOL_STEP_COPIES = 2
 
 #: Share of the bytes left beside the weights that the pool tiers take; the

@@ -120,11 +120,14 @@ def test_matmul3d_compiles_at_a_kernel_bench_shape(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("kernel", ["flash", "page walk"])
+@pytest.mark.parametrize("kernel", ["flash", "page walk",
+                                    "page walk, stacked pool"])
 def test_kernels_compile_split_over_a_2x2_mesh(topo, tpu_routes, kernel):
     """mistral-nemo-12b widths on a data x model 2x2 mesh of the described
     chips: the kernels run per shard (``over_heads``), so the program holds
-    the Pallas kernel and gathers nothing."""
+    the Pallas kernel and gathers nothing. The stacked pool is the one the
+    decode chunk's layer scan carries, its heads on axis 2, read at a
+    layer index."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
@@ -136,12 +139,20 @@ def test_kernels_compile_split_over_a_2x2_mesh(topo, tpu_routes, kernel):
         fn = lambda q, k, v: ops.attention(q, k, v, causal=True)
         args = (_spec(at("data", "model"), (slots, hq, 2048, HEAD_DIM)),
                 *[_spec(at("data", "model"), (slots, hkv, 2048, HEAD_DIM))] * 2)
-    else:
+    elif kernel == "page walk":
         fn = paged_decode_attention
         pages = _spec(at(None, "model"), (1025, hkv, 16, HEAD_DIM))
         args = (_spec(at("data", "model"), (slots, hq, 1, HEAD_DIM)),
                 pages, pages, _spec(at("data"), (slots, 128), jnp.int32),
                 _spec(at("data"), (slots,), jnp.int32))
+    else:
+        fn = lambda q, k, v, t, n, layer: paged_decode_attention(
+            q, k, v, t, n, layer=layer)
+        pages = _spec(at(None, None, "model"), (3, 1025, hkv, 16, HEAD_DIM))
+        args = (_spec(at("data", "model"), (slots, hq, 1, HEAD_DIM)),
+                pages, pages, _spec(at("data"), (slots, 128), jnp.int32),
+                _spec(at("data"), (slots,), jnp.int32),
+                _spec(at(), (), jnp.int32))
     with jax.set_mesh(mesh):
         text = _compiled_text(fn, *args)
     assert "tpu_custom_call" in text
@@ -153,14 +164,13 @@ def test_kernels_compile_split_over_a_2x2_mesh(topo, tpu_routes, kernel):
 V5E_BYTES_LIMIT = 16909336064
 
 
-def test_serving_steps_fit_beside_the_weights_at_the_pool_budget(one_chip,
-                                                                tpu_routes):
+@pytest.fixture(scope="module")
+def served_steps(one_chip):
     """qwen2.5-3b at full width and depth, its pool tiers sized as
-    ``pool_bytes_beside`` sizes them on a v5e. Compiled for the chip, the
-    paged admission and pooled decode chunk alias the donated pool to their
-    output, and each step's peak, with the spill tier beside it, fits in the
-    device's ``bytes_limit``. Undonated, or with more pool copies than
-    ``POOL_STEP_COPIES`` charges, the decode chunk does not fit."""
+    ``pool_bytes_beside`` sizes them on a v5e, and its paged admission and
+    pooled decode chunk compiled for the chip: ``(memory_analysis()`` by
+    step, the pool's bytes, its layer-0 pages' bytes, the spill tier's
+    bytes)``."""
     from repro.configs import get_config
     from repro.models import build_model
     from repro.serve.engine import Engine, EngineConfig, serving_params
@@ -186,15 +196,39 @@ def test_serving_steps_fit_beside_the_weights_at_the_pool_budget(one_chip,
     engine.pools._build = lambda make: place(jax.eval_shape(make))
     pool, spill = engine.init_paged_pool(Scheduler(n_slots=4, pages=geom))
     i32 = lambda *shape: _spec(one_chip, shape, jnp.int32)
-    steps = {
-        "admit": engine.prefill_role._make_paged_admit_fn(geom).lower(
-            params, i32(1, 4096), i32(), i32(), i32(),
-            i32(geom.max_pages_per_slot), pool),
-        "decode": engine.decode_role.pool_chunk(8).lower(params, pool),
-    }
-    for name, lowered in steps.items():
-        mem = lowered.compile().memory_analysis()
-        assert mem.alias_size_in_bytes >= nbytes(pool), name
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "on_tpu", lambda: True)
+        steps = {
+            "admit": engine.prefill_role._make_paged_admit_fn(geom).lower(
+                params, i32(1, 4096), i32(), i32(), i32(),
+                i32(geom.max_pages_per_slot), pool),
+            "decode": engine.decode_role.pool_chunk(8).lower(params, pool),
+        }
+        mems = {name: lowered.compile().memory_analysis()
+                for name, lowered in steps.items()}
+    return mems, nbytes(pool), nbytes(pool.state), nbytes(spill)
+
+
+def test_serving_steps_fit_beside_the_weights_at_the_pool_budget(
+        served_steps):
+    """Compiled for the chip, the paged admission and pooled decode chunk
+    alias the donated pool to their output, and each step's peak, with the
+    spill tier beside it, fits in the device's ``bytes_limit``. Undonated,
+    or with more pool copies than ``POOL_STEP_COPIES`` charges, the decode
+    chunk does not fit."""
+    mems, pool_bytes, _, spill_bytes = served_steps
+    for name, mem in mems.items():
+        assert mem.alias_size_in_bytes >= pool_bytes, name
         peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-        assert peak + nbytes(spill) <= V5E_BYTES_LIMIT, (name, peak)
+        assert peak + spill_bytes <= V5E_BYTES_LIMIT, (name, peak)
+
+
+def test_decode_chunk_updates_the_pool_in_place(served_steps):
+    """The pooled decode chunk's layer scan carries the stacked pool and
+    writes each step's rows into it where it lies, so its scratch holds
+    no second pool: the compiled temp bytes stay under half the layer-0
+    pool's."""
+    mems, _, pages, _ = served_steps
+    assert mems["decode"].temp_size_in_bytes < pages // 2, (
+        mems["decode"].temp_size_in_bytes, pages)

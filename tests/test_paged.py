@@ -171,22 +171,43 @@ def test_paged_stream_reuse_and_rejection():
             assert 1 <= len(req.tokens) <= req.max_new_tokens
 
 
+@pytest.mark.parametrize("cfg", [TINY, TINY_MLA, TINY_HYBRID],
+                         ids=lambda c: c.name)
+def test_decode_chunk_carries_every_paged_leaf_in_place(cfg):
+    """Serving a paged pool reports, in ``last_stats`` and on every
+    ``engine.generate`` span, that the decode forward's layer scan carries
+    all of the pool's paged leaves (K/V pages, MLA latents; recurrent
+    state is per slot, not paged) in place."""
+    from repro.serve import spans
+    model = build_model(cfg)
+    eng = Engine(model, model.init(jax.random.PRNGKey(0)),
+                 EngineConfig(max_len=32, eos_token=1, sync_interval=4))
+    sch = sm.Scheduler(n_slots=3, pages=_tight_geometry(cfg))
+    for n in (6, 9):
+        sch.submit(np.arange(2, 2 + n, dtype=np.int32), 5)
+    spans.clear()
+    stats = eng.serve(scheduler=sch).stats
+    attn = sum(kind.attn != "mamba" for g in cfg.layer_groups()
+               for kind in g.pattern)
+    assert stats["pool_leaves_paged"] == 2 * attn
+    assert stats["pool_leaves_in_place"] == stats["pool_leaves_paged"]
+    gens = [sp for sp in spans.recent() if sp.name == "engine.generate"]
+    assert gens and all(
+        sp.args["pool_leaves_in_place"] == sp.args["pool_leaves_paged"]
+        == 2 * attn for sp in gens)
+
+
 # ------------------------------------------------------ page-walk kernel
 
-@pytest.mark.parametrize("window", [None, "straddle"])
-@pytest.mark.parametrize("partial_block", [False, True])
-@pytest.mark.parametrize("page_tokens", [8, 16])
-def test_paged_flash_decode_matches_oracle(page_tokens, partial_block,
-                                           window):
-    """The Pallas page-walk kernel (interpret mode on CPU) == the
-    dense-masked oracle over each slot's contiguous view, within
-    online-softmax tolerance. The slots' frontiers sit at 0, either side of
-    and on the first block edge, and on the last position; pages past a
-    frontier map to null page 0 (finite junk); two slots alias one physical
-    page; ``P_max`` is whole blocks, or has a partial block at its end; a
-    window reaches back across a block edge."""
+def _walk_case(page_tokens, partial_block, window, n_layers):
+    """Inputs of a page walk over ``n_layers`` stacked layers of pages, and
+    per layer the dense-masked oracle over each slot's contiguous view.
+    The slots' frontiers sit at 0, either side of and on the first block
+    edge, and on the last position; pages past a frontier map to null page
+    0 (finite junk); two slots alias one physical page; ``P_max`` is whole
+    blocks, or has a partial block at its end; a window reaches back across
+    a block edge."""
     from repro.kernels.paged_attention import (decode_attention_masked,
-                                               paged_decode_attention,
                                                walk_pages_per_block)
     rng = np.random.RandomState(page_tokens + 2 * partial_block)
     hq, hkv, d, pt = 4, 2, 32, page_tokens
@@ -204,19 +225,56 @@ def test_paged_flash_decode_matches_oracle(page_tokens, partial_block,
         for p in range(f // pt + 1):
             bt[i, p], n_pages = n_pages, n_pages + 1
     bt[2, 0] = bt[1, 0]                        # aliased prefix page
-    kp = rng.randn(n_pages, hkv, pt, d).astype(np.float32)
-    vp = rng.randn(n_pages, hkv, pt, d).astype(np.float32)
-    kp[0] = vp[0] = 1e3                        # null-page junk
-    k = np.concatenate([kp[bt[:, p]] for p in range(p_max)], axis=2)
-    v = np.concatenate([vp[bt[:, p]] for p in range(p_max)], axis=2)
+    kp = rng.randn(n_layers, n_pages, hkv, pt, d).astype(np.float32)
+    vp = rng.randn(n_layers, n_pages, hkv, pt, d).astype(np.float32)
+    kp[:, 0] = vp[:, 0] = 1e3                  # null-page junk
     q = jnp.asarray(rng.randn(b, hq, 1, d), jnp.float32)
     cache_len = jnp.asarray(cache_len)
-    want = decode_attention_masked(q, jnp.asarray(k), jnp.asarray(v),
-                                   cache_len, window=win)
-    got = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp),
-                                 jnp.asarray(bt), cache_len, window=win,
+    want = []
+    for layer in range(n_layers):
+        k = np.concatenate([kp[layer, bt[:, p]] for p in range(p_max)],
+                           axis=2)
+        v = np.concatenate([vp[layer, bt[:, p]] for p in range(p_max)],
+                           axis=2)
+        want.append(decode_attention_masked(q, jnp.asarray(k), jnp.asarray(v),
+                                            cache_len, window=win))
+    return (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt), cache_len,
+            win, want)
+
+
+@pytest.mark.parametrize("window", [None, "straddle"])
+@pytest.mark.parametrize("partial_block", [False, True])
+@pytest.mark.parametrize("page_tokens", [8, 16])
+def test_paged_flash_decode_matches_oracle(page_tokens, partial_block,
+                                           window):
+    """The Pallas page-walk kernel (interpret mode on CPU) over one layer's
+    pages == the dense-masked oracle over each slot's contiguous view,
+    within online-softmax tolerance (:func:`_walk_case`)."""
+    from repro.kernels.paged_attention import paged_decode_attention
+    q, kp, vp, bt, cache_len, win, want = _walk_case(
+        page_tokens, partial_block, window, n_layers=1)
+    got = paged_decode_attention(q, kp[0], vp[0], bt, cache_len, window=win,
                                  impl="pallas")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want[0]),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("window", [None, "straddle"])
+@pytest.mark.parametrize("partial_block", [False, True])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_flash_decode_reads_a_stacked_pool(layer, partial_block,
+                                                 window):
+    """The walk over the stacked pool the decode chunk carries, (L = 3,
+    n_pages, Hkv, pt, D), at a traced layer index == the oracle over that
+    layer's gathered pages: it reads layer ``layer`` where it lies and no
+    other."""
+    from repro.kernels.paged_attention import paged_decode_attention
+    q, kp, vp, bt, cache_len, win, want = _walk_case(
+        16, partial_block, window, n_layers=3)
+    got = paged_decode_attention(q, kp, vp, bt, cache_len,
+                                 layer=jnp.int32(layer), window=win,
+                                 impl="pallas")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want[layer]),
                                rtol=2e-4, atol=2e-4)
 
 
